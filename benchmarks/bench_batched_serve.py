@@ -47,6 +47,7 @@ from benchmarks.common import out_write
 from repro.api import EngineCfg, StorInfer, SystemCfg, make_embedder, \
     make_index, tier_of
 from repro.core.runtime import BatchedRuntimeCfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.store import PrecomputedStore
 
 
@@ -301,6 +302,7 @@ def bench_quantized_flat(n_rows, n_q, batch, s_th, speedup_floor,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small store/query count for CI")

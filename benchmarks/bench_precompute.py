@@ -40,6 +40,7 @@ from repro.core.generator import (GenCfg, QueryGenerator, SyntheticOracleLM,
                                   chunk_key)
 from repro.core.kb import build_kb
 from repro.core.precompute import BuildKilled, PrecomputeCfg
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def kb_env(n_docs: int, seed: int = 0):
@@ -156,6 +157,7 @@ def bench_resume(td: Path, n_rows: int, wave: int):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small targets for CI")

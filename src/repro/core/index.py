@@ -98,10 +98,12 @@ class DeviceStore:
 
     Residency layout per backend (``layout=``):
 
-    * ``"kernel"`` (default on TPU) — shards stay in their stored dtype:
-      int8 values + per-row f32 scales for quantized stores (feeding the
-      fused ``mips_topk_int8`` Pallas kernel; hot-path HBM bytes drop 4x
-      vs fp32), fp16/fp32 rows otherwise (``mips_topk``).
+    * ``"kernel"`` (default on TPU) — quantized stores stay int8 values +
+      per-row f32 scales (feeding the fused ``mips_topk_int8`` Pallas
+      kernel; hot-path HBM bytes drop 4x vs fp32). Float stores are
+      shipped in their stored dtype and upcast to f32 once on the device
+      (``mips_topk``): v5e cannot load f16 vectors, and f32 keeps the
+      scores exact against an f32 reference.
     * ``"gemm"`` (default on CPU) — no int8 MXU exists and XLA's CPU int8
       GEMM is several times SLOWER than Eigen's fp32, so shards are
       dequantized/upcast ONCE at upload into the transposed (D, N) fp32
@@ -129,7 +131,7 @@ class DeviceStore:
         self.dim: Optional[int] = None
         self.quantized = False
         self._xT = None        # gemm: (D, N) f32
-        self._x = None         # kernel: (N, D) stored dtype
+        self._x = None         # kernel: (N, D) int8, or f32 for floats
         self._scales = None    # kernel + quantized: (N,) f32
         self.uploads = 0       # host→device transfers (tests/benchmarks)
         # background §3.1 rebuilds sync() deltas while the serving path
@@ -199,7 +201,8 @@ class DeviceStore:
                 else jnp.concatenate(ss, 0)
         else:
             xs = ([] if self._x is None else [self._x]) \
-                + [jnp.asarray(gather(c)) for c in chunks]
+                + [jnp.asarray(gather(c)).astype(jnp.float32)
+                   for c in chunks]
             self._x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, 0)
         self.uploads += len(chunks)
         self.n_rows = n
@@ -236,8 +239,6 @@ class DeviceStore:
                                   x, scales, k)
         else:
             from repro.kernels.ops import mips_topk
-            # the kernel scores fp16/fp32 tiles as-is (the MXU dot
-            # upcasts in-register) — no per-search fp32 materialization
             v, i = mips_topk(jnp.asarray(q), x, k)
         return np.asarray(v), np.asarray(i)
 
@@ -701,9 +702,10 @@ class ShardedIndex:
     """Mesh-sharded exact MIPS: rows over ``shard_axis``, distributed top-k.
 
     Quantized views shard the int8 values + per-row scales as-is (4x less
-    HBM per device; each local scan scores its int8 shard and dequantizes
-    in place — see distributed/topk.py); float inputs shard fp32 exactly
-    as before."""
+    HBM per device); queries are quantized like the flat kernel layout's,
+    and each local scan scores its int8 shard and dequantizes in place
+    (distributed/topk.py), so both tiers return the same scores. Float
+    inputs shard fp32."""
 
     def __init__(self, embs: np.ndarray, mesh, shard_axis: str = "model"):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -738,11 +740,17 @@ class ShardedIndex:
 
     def search(self, queries: np.ndarray, k: int):
         from repro.distributed.topk import sharded_mips_topk
-        q = jnp.asarray(np.asarray(queries, np.float32))
-        v, i = sharded_mips_topk(
-            q, self.embs, k, mesh=self.mesh, shard_axis=self.shard_axis,
-            scales=self.scales,
-            n_real=self.n_real if self.scales is not None else None)
+        q = np.asarray(queries, np.float32)
+        if self.scales is None:
+            v, i = sharded_mips_topk(jnp.asarray(q), self.embs, k,
+                                     mesh=self.mesh,
+                                     shard_axis=self.shard_axis)
+        else:
+            q8, qs = quantize_rows(q)
+            v, i = sharded_mips_topk(
+                jnp.asarray(q8), self.embs, k, mesh=self.mesh,
+                shard_axis=self.shard_axis, scales=self.scales,
+                n_real=self.n_real, q_scale=jnp.asarray(qs))
         return np.asarray(v), np.asarray(i)
 
     def __len__(self):

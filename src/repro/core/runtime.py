@@ -48,6 +48,9 @@ class QueryResult:
     latency_s: float
     chunks_run: int = 0
     cancelled: bool = False   # an LLM decode was started and hit-cancelled
+    token_ids: List[int] = dataclasses.field(default_factory=list)
+    #                           the miss decode's ids (the response text
+    #                           drops ids outside the tokenizer's vocab)
 
 
 @dataclasses.dataclass
@@ -118,11 +121,11 @@ class StorInferRuntime:
                 cancelled=bool(session is not None and session.cancelled))
 
         # miss: let the LLM finish (it kept decoding the whole time)
-        llm_text = ""
+        llm_text, ids = "", []
         if session is not None:
             while not session.done:
                 session.step_chunk()
-            llm_text = session.text()
+            llm_text, ids = session.text(), list(session.out_ids)
             if self.cfg.add_misses:
                 # the race's search already encoded this query — reuse it
                 self.store.add_batch(emb, [text], [llm_text])
@@ -131,7 +134,7 @@ class StorInferRuntime:
             matched_query=None, search_s=search_s,
             llm_s=(session.decode_s + session.prefill_s) if session else 0.0,
             latency_s=time.perf_counter() - t0,
-            chunks_run=session.chunks_run if session else 0)
+            chunks_run=session.chunks_run if session else 0, token_ids=ids)
 
     # -- batched search (benchmarks) --------------------------------------------
     def search_batch(self, texts, k: int = 1):
@@ -329,7 +332,8 @@ class BatchedRuntime:
                 results.append(QueryResult(
                     response=resp, source="llm", hit=False, score=score,
                     matched_query=None, search_s=search_s, llm_s=llm_s,
-                    latency_s=done - t0, chunks_run=chunks))
+                    latency_s=done - t0, chunks_run=chunks,
+                    token_ids=list(req.out_ids) if req is not None else []))
 
         n_hits = len(texts) - len(miss_idx)
         with self._stats_lock:

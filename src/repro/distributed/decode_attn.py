@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 NEG_INF = -1e30
 
@@ -89,7 +88,7 @@ def gqa_decode_seq_sharded(q, k_new, v_new, kc, vc, cache_len, *, mesh,
         out = _combine(o, m, l, seq_axis)                   # (b,Hkv,G,D)
         return out.reshape(out.shape[0], 1, Hq * D), kc, vc
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   P(bspec, seq_axis), P(bspec, seq_axis), P()),
@@ -131,7 +130,7 @@ def mla_decode_seq_sharded(q_c, q_r, ckv_new, krope_new, ckv_c, krope_c,
         out = _combine(o, m, l, seq_axis)                  # (b,H,1,r)
         return jnp.moveaxis(out, 1, 2), ckv_c, krope_c     # (b,1,H,r)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec), P(bspec),
                   P(bspec, seq_axis), P(bspec, seq_axis), P()),

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 from repro.models import moe as Moe
 from repro.models.layers import mlp
 
@@ -94,7 +92,7 @@ def moe_ffn_ep(cfg, p, x, *, mesh, ep_axis="model", batch_axes=("data",)):
         shared = {"w1": {"w": jnp.zeros((0,), x.dtype)},
                   "w2": {"w": jnp.zeros((0,), x.dtype)},
                   "w3": {"w": jnp.zeros((0,), x.dtype)}}
-    sm = shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, ep_axis), P(), P(ep_axis), P()),
         out_specs=(P(bspec, ep_axis), P()),

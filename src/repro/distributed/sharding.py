@@ -26,18 +26,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 TP, FSDP, BATCH, SEQ, EP, REP = "TP", "FSDP", "BATCH", "SEQ", "EP", "REP"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-compat shard_map: newer jax exposes ``jax.shard_map`` with
-    ``check_vma``; older releases only have the experimental one with
-    ``check_rep``. All in-repo call sites go through this wrapper."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def _resolve(logical: str, dim: int, mesh) -> Optional[object]:
     """Map a logical axis to mesh axes, honoring divisibility."""
     names = mesh.axis_names

@@ -1,17 +1,18 @@
 """Distributed top-k for the mesh-sharded MIPS index.
 
 The precomputed-query embedding matrix is row-sharded over the "model" axis;
-each device scans its shard (one matmul — the Pallas ``mips_topk`` kernel on
-real TPUs), takes a local top-k, then an all-gather of the (k-sized)
-candidate lists and a final top-k. Traffic per query: shards * k * 8 bytes —
-independent of store size N.
+each device scans its shard (one matmul), takes a local top-k, then an
+all-gather of the (k-sized) candidate lists and a final top-k. Traffic per
+query: shards * k * 8 bytes — independent of store size N.
 
 Quantized stores shard int8 values + per-row f32 scales (``scales=``): the
-local scan scores the int8 shard directly (int8 operand, f32 accumulate —
-the MXU's native mixed mode on TPU) and fuses the scale dequant, so each
-device holds and streams ~1/4 of the fp32 bytes. int8 cannot encode the
-float path's -1e4 padding fill, so padded rows are masked out by global
-row id instead (``n_real=``).
+local scan scores an int8 query block against the int8 shard with exact
+int32 accumulation, then dequantizes in the same order as the one-chip
+int8 kernel (acc -> f32, * q_scale, * x_scale), so each device holds and
+streams ~1/4 of the fp32 bytes and the sharded tier returns the same
+scores as the flat tier. int8 cannot encode the float path's -1e4
+padding fill, so padded rows are masked out by global row id instead
+(``n_real=``).
 """
 from __future__ import annotations
 
@@ -19,65 +20,48 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
-NEG = -1e30
+from repro.kernels.mips_topk import NEG, select_topk
 
 
 def sharded_mips_topk(queries, emb, k, *, mesh, shard_axis="model",
-                      local_scan=None, scales=None, n_real=None):
+                      scales=None, n_real=None, q_scale=None):
     """queries: (Q, D) replicated; emb: (N, D) row-sharded over shard_axis.
 
-    Returns (scores (Q, k), indices (Q, k)) — replicated, GLOBAL row ids.
-    ``local_scan(q, e, k) -> (vals, idx)`` optionally overrides the local
-    shard scan (e.g. with the Pallas kernel) on the float path; default is
-    matmul + lax.top_k. ``scales`` (row-sharded (N,) f32) switches to the
-    int8 shard scan; ``n_real`` masks padded rows (global id >= n_real)
-    before the local top-k.
+    Returns (scores (Q, k), indices (Q, k)) — replicated, GLOBAL row ids,
+    ordered by (value desc, index asc) like the one-chip kernels.
+    ``scales`` (row-sharded (N,) f32) switches to the int8 shard scan,
+    which takes int8 ``queries`` with their per-row ``q_scale`` (Q,) f32;
+    ``n_real`` masks padded rows (global id >= n_real) before the local
+    top-k.
     """
 
-    def default_scan(q, e, k):
-        s = q.astype(jnp.float32) @ e.T.astype(jnp.float32)
-        return jax.lax.top_k(s, k)
-
-    scan = local_scan or default_scan
-
-    def masked(s, offset):
-        if n_real is None:
-            return s
-        rows = offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        return jnp.where(rows < n_real, s, NEG)
-
-    def combine(v, i, offset):
-        i = i + offset
+    def local_topk(s, e):
+        rows = jax.lax.axis_index(shard_axis) * e.shape[0] \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if n_real is not None:
+            s = jnp.where(rows < n_real, s, NEG)
+        v, i = select_topk(s, rows, k)
         vg = jax.lax.all_gather(v, shard_axis, axis=1, tiled=True)
         ig = jax.lax.all_gather(i, shard_axis, axis=1, tiled=True)
-        vf, pos = jax.lax.top_k(vg, k)
-        return vf, jnp.take_along_axis(ig, pos, axis=1)
+        return select_topk(vg, ig, k)
 
     if scales is not None:
-        def local(q, e, sc):
-            offset = jax.lax.axis_index(shard_axis) * e.shape[0]
+        def local(q, qs, e, sc):
             s = jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            v, i = jax.lax.top_k(masked(s * sc[None, :], offset), k)
-            return combine(v, i, offset)
+                                    preferred_element_type=jnp.int32)
+            return local_topk(
+                s.astype(jnp.float32) * qs[:, None] * sc[None, :], e)
 
-        sm = shard_map(local, mesh=mesh,
-                       in_specs=(P(), P(shard_axis), P(shard_axis)),
-                       out_specs=(P(), P()), check_vma=False)
-        return sm(queries, emb, scales)
+        sm = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P(), P(), P(shard_axis),
+                                     P(shard_axis)),
+                           out_specs=(P(), P()), check_vma=False)
+        return sm(queries, q_scale, emb, scales)
 
     def local(q, e):
-        offset = jax.lax.axis_index(shard_axis) * e.shape[0]
-        if local_scan is None:
-            s = masked(q.astype(jnp.float32) @ e.T.astype(jnp.float32),
-                       offset)
-            v, i = jax.lax.top_k(s, k)
-        else:
-            v, i = scan(q, e, k)
-        return combine(v, i, offset)
+        return local_topk(q.astype(jnp.float32) @ e.T.astype(jnp.float32),
+                          e)
 
-    sm = shard_map(local, mesh=mesh, in_specs=(P(), P(shard_axis)),
-                   out_specs=(P(), P()), check_vma=False)
+    sm = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(shard_axis)),
+                       out_specs=(P(), P()), check_vma=False)
     return sm(queries, emb)

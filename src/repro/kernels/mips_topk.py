@@ -26,8 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG = -1e30
-# tile_topk pads candidate indices with this sentinel; it must sort after
-# every real (< 2^24) row id under the (value desc, index asc) order
+# the running candidate list starts as (NEG, _IDX_PAD) entries; the index
+# must sort after every real (< 2^24) row id under (value desc, index asc)
 _IDX_PAD = 2 ** 30
 
 
@@ -39,103 +39,82 @@ def _ge(av, ai, bv, bi):
     return (av > bv) | ((av == bv) & (ai <= bi))
 
 
-def _chunk_topk(s, k, col0):
-    """Exact top-k of one (Q, c) score chunk by k masked argmax passes,
-    emitted in (value desc, index asc) order. ``col0`` is the chunk's
-    first column; returned indices are tile-local."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _first_max(v, i):
+    """Per row of (Q, M) values ``v`` with indices ``i``: the max and the
+    lowest index holding it. Spelled out rather than argmax / lax.top_k,
+    whose tie-break neither Mosaic nor XLA on TPU promises."""
+    m = jnp.max(v, axis=1)
+    return m, jnp.min(jnp.where(v == m[:, None], i, _IDX_PAD), axis=1)
+
+
+def select_topk(v, i, k):
+    """Exact top-k of (Q, M) candidates ``v`` with int32 indices ``i``,
+    ordered by (value desc, index asc): k passes of ``_first_max``."""
     vals, idxs = [], []
-    for _ in range(k):
-        m = jnp.max(s, axis=1)
-        a = jnp.argmax(s, axis=1).astype(jnp.int32)   # first max: lowest idx
+    for p in range(k):
+        m, a = _first_max(v, i)
         vals.append(m)
-        idxs.append(a + col0)
-        s = jnp.where(cols == a[:, None], NEG, s)
+        idxs.append(a)
+        if p + 1 < k:
+            v = jnp.where(i == a[:, None], NEG, v)
     return jnp.stack(vals, axis=1), jnp.stack(idxs, axis=1)
 
 
-def _bitonic_merge_desc(v, i):
-    """Sort a bitonic (Q, m) candidate list descending (m a power of two):
-    log2(m) compare-exchange stages, each one reshape + min/max — no
-    gathers, so it lowers cleanly on the VPU."""
-    m = v.shape[-1]
-    stride = m // 2
-    while stride >= 1:
-        shp = v.shape
-        v4 = v.reshape(shp[:-1] + (m // (2 * stride), 2, stride))
-        i4 = i.reshape(v4.shape)
-        av, bv = v4[..., 0, :], v4[..., 1, :]
-        ai, bi = i4[..., 0, :], i4[..., 1, :]
-        ge = _ge(av, ai, bv, bi)
-        v = jnp.stack([jnp.where(ge, av, bv), jnp.where(ge, bv, av)],
-                      axis=-2).reshape(shp)
-        i = jnp.stack([jnp.where(ge, ai, bi), jnp.where(ge, bi, ai)],
-                      axis=-2).reshape(shp)
-        stride //= 2
-    return v, i
+def _fold_chunk(rv, ri, s, col0):
+    """Top-k of the union of a running candidate list (rv, ri) (Q, k) and
+    one (Q, c) score chunk whose first column is ``col0``, emitted in
+    (value desc, index asc) order.
 
-
-def _merge_desc(rv, ri, cv, ci):
-    """Merge two descending-sorted (Q, m) candidate lists into the top-m
-    of their union. Max-pairing rv[j] against reversed cv picks the top-m
-    multiset in one element-wise pass (the first stage of a bitonic merge
-    of [rv ; reverse(cv)]); the result is bitonic, so log2(m) further
-    stages restore descending order."""
-    cv_r, ci_r = cv[..., ::-1], ci[..., ::-1]
-    take = _ge(rv, ri, cv_r, ci_r)
-    v = jnp.where(take, rv, cv_r)
-    i = jnp.where(take, ri, ci_r)
-    return _bitonic_merge_desc(v, i)
+    Each of the k passes takes the best of the chunk and the best of the
+    list (``_first_max``), keeps the winner under ``_ge`` and masks it out
+    of its side. Only lane reductions and element-wise selects: no
+    reversal and no reshape of the lane dimension, which Mosaic does not
+    lower."""
+    k = rv.shape[1]
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    vals, idxs = [], []
+    for p in range(k):
+        mc, ic = _first_max(s, cols)
+        mr, ir = _first_max(rv, ri)
+        take = _ge(mr, ir, mc, ic)
+        vals.append(jnp.where(take, mr, mc))
+        idxs.append(jnp.where(take, ir, ic))
+        if p + 1 < k:
+            t = take[:, None]
+            rv = jnp.where(t & (ri == ir[:, None]), NEG, rv)
+            s = jnp.where(~t & (cols == ic[:, None]), NEG, s)
+    return jnp.stack(vals, axis=1), jnp.stack(idxs, axis=1)
 
 
 def tile_topk(s, k, *, chunk=128):
     """Exact top-k along the last axis of ``s`` (Q, T), ordered by
     (value desc, index asc). Returns (vals (Q, k), idx (Q, k) int32).
 
-    Replaces the old k-pass masked argmax over the FULL tile (which also
-    rewrote the whole (Q, T) block with a masking ``where`` every pass —
-    2k full-tile traversals): the tile is streamed once in lane-width
-    chunks, each chunk's top-k is selected inside that small hot block,
-    and the running candidate list is folded in with an O(k log k)
-    bitonic max-pairing merge on (Q, k). The (Q, T) score block is read
-    once and never written back.
+    The tile is streamed once in lane-width chunks; each chunk is folded
+    into a running (Q, k) candidate list inside that small hot block, so
+    the (Q, T) score block is read once and never written back.
     """
     Q, T = s.shape
     if k > T:
         raise ValueError(f"tile_topk: k={k} exceeds tile width {T}")
     c = min(chunk, T)
-    if k > c or T % c:
-        c = T                      # rare big-k / ragged tile: single chunk
-    if c == T:                     # one chunk: plain selection, no merge
-        return _chunk_topk(s, k, 0)
-    # pad the candidate lists to a power of two for the merge network
-    k2 = 1
-    while k2 < k:
-        k2 *= 2
-    pad_v = jnp.full((Q, k2 - k), NEG, s.dtype)
-    pad_i = jnp.full((Q, k2 - k), _IDX_PAD, jnp.int32)
-
-    def padded(v, i):
-        if k2 == k:
-            return v, i
-        return (jnp.concatenate([v, pad_v], axis=1),
-                jnp.concatenate([i, pad_i], axis=1))
-
-    rv = ri = None
+    if T % c:
+        c = T                      # ragged tile: single chunk
+    rv = jnp.full((Q, k), NEG, s.dtype)
+    ri = jnp.full((Q, k), _IDX_PAD, jnp.int32)
     for lo in range(0, T, c):
-        cv, ci = padded(*_chunk_topk(s[:, lo:lo + c], k, lo))
-        if rv is None:
-            rv, ri = cv, ci
-        else:
-            rv, ri = _merge_desc(rv, ri, cv, ci)
-    return rv[:, :k], ri[:, :k]
+        rv, ri = _fold_chunk(rv, ri, s[:, lo:lo + c], lo)
+    return rv, ri
 
 
 def _mips_kernel(q_ref, x_ref, vals_ref, idx_ref, *, k, tile_n, n_real):
     i = pl.program_id(0)
     q = q_ref[...]                                    # (Q, D)
     x = x_ref[...]                                    # (TILE_N, D)
-    s = jnp.dot(q, x.T, preferred_element_type=jnp.float32)  # (Q, TILE_N)
+    # HIGHEST: full f32 products on the MXU, so scores match an f32
+    # reference instead of a bf16-pass approximation
+    s = jnp.dot(q, x.T, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)        # (Q, TILE_N)
     # mask padded store rows (beyond n_real)
     row_global = i * tile_n + jax.lax.broadcasted_iota(jnp.int32,
                                                        s.shape, 1)
@@ -146,8 +125,8 @@ def _mips_kernel(q_ref, x_ref, vals_ref, idx_ref, *, k, tile_n, n_real):
 
 
 def mips_topk_pallas(q, x, k, *, tile_n=512, interpret=True):
-    """q: (Q, D) f32; x: (N, D) float (f32/f16/bf16 — the MXU dot upcasts
-    once in-register, so fp16 shards never materialize an fp32 copy).
+    """q: (Q, D) f32; x: (N, D) f32 (v5e cannot load f16 vectors, so
+    fp16 stores are upcast once at upload; see core/index.DeviceStore).
     Returns per-tile candidates (vals (nt, Q, k), idx-global (nt, Q, k))."""
     Q, D = q.shape
     N = x.shape[0]

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention import (combine_splits,
                                             decode_attention_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.mips_topk import mips_topk_pallas
+from repro.kernels.mips_topk import mips_topk_pallas, select_topk
 from repro.kernels.mips_topk_int8 import mips_topk_int8_pallas
 
 
@@ -23,16 +23,12 @@ def _default_interpret():
 
 
 def _combine_tiles(vals, idx, k):
-    """Reduce per-tile candidates (nt, Q, k) to the global top-k. Tiles are
-    flattened in (tile, rank) order, which is (value desc, index asc)
-    within a tile and index-asc across tiles — so lax.top_k's
-    first-occurrence tie-break preserves the kernels' (value desc, index
-    asc) contract end to end."""
+    """Reduce per-tile candidates (nt, Q, k) to the global top-k under the
+    kernels' (value desc, index asc) contract."""
     nt, Q = vals.shape[0], vals.shape[1]
     vflat = jnp.moveaxis(vals, 0, 1).reshape(Q, nt * k)
     iflat = jnp.moveaxis(idx, 0, 1).reshape(Q, nt * k)
-    v, pos = jax.lax.top_k(vflat, k)
-    return v, jnp.take_along_axis(iflat, pos, axis=1)
+    return select_topk(vflat, iflat, k)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))

@@ -20,9 +20,11 @@ from pathlib import Path
 from repro.api import StorInfer, SystemCfg, tier_of
 from repro.core.kb import build_kb
 from repro.core.precompute import STATE_KEY, PrecomputeCfg
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="squad",
                     choices=("squad", "narrativeqa", "triviaqa"))

@@ -19,9 +19,11 @@ import numpy as np
 from repro.api import EngineCfg, StorInfer, SystemCfg
 from repro.core.kb import build_kb, sample_user_queries
 from repro.core.tokenizer import Tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     # BooleanOptionalAction: plain store_true with default=True made the

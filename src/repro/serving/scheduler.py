@@ -629,7 +629,8 @@ class ServingPipeline:
             response=text, source="llm", hit=False, score=s.score,
             matched_query=None, search_s=s.t_search - s.t_admit,
             llm_s=now - s.t_search, latency_s=now - s.t_admit,
-            chunks_run=req.chunks, cancelled=req.cancelled)
+            chunks_run=req.chunks, cancelled=req.cancelled,
+            token_ids=list(req.out_ids))
         self._account(qr)
         try:
             s.future.set_result(qr)

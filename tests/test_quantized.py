@@ -215,14 +215,37 @@ def test_device_store_fp16_ships_native_and_casts_once(tmp_path):
     ref = q @ np.asarray(st.embeddings(), np.float32).T
     np.testing.assert_allclose(
         v, np.sort(ref, axis=1)[:, ::-1][:, :4], rtol=1e-3, atol=1e-4)
-    # kernel layout keeps the fp16 operand resident AS fp16 (the Pallas
-    # dot upcasts in-register; no per-search fp32 copy) and agrees
+    # kernel layout ships fp16 and upcasts ONCE at upload (v5e cannot
+    # load f16 vectors); searches then run on the resident f32 operand
     devk = DeviceStore(st, layout="kernel")
     import jax.numpy as jnp
-    assert devk._x.dtype == jnp.float16
+    assert devk._x.dtype == jnp.float32
     vk, ik = devk.search(q, 4)
     np.testing.assert_allclose(vk, v, rtol=1e-3, atol=1e-4)
     np.testing.assert_array_equal(ik, i)
+    st.close()
+
+
+@pytest.mark.parametrize("n,d,k", [(700, 384, 1), (1300, 48, 8)])
+def test_device_store_fp16_kernel_layout_scores_like_ref(tmp_path, n, d, k):
+    """The kernel layout of a float16 store (f32 residency, Pallas float
+    kernel in interpret mode) returns ``ref.mips_topk_ref``'s rows and
+    scores over the same fp16-rounded rows."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ref
+    x = _rows(n, d=d, seed=n)
+    st = PrecomputedStore(tmp_path / "s", dim=d, emb_dtype="float16")
+    st.add_batch(x, ["q"] * n, ["r"] * n)
+    st.flush()
+    q = _rows(32, d=d, seed=n + 1)
+    v, i = DeviceStore(st, layout="kernel").search(q, k)
+    x16 = np.asarray(st.embeddings()).astype(np.float32)
+    vr, ir = ref.mips_topk_ref(jnp.asarray(q), jnp.asarray(x16), k)
+    np.testing.assert_array_equal(i, np.asarray(ir))
+    # same f32 products, summed in another order (one dot per tile vs one
+    # matmul): unit-norm scores agree to a few f32 ulps
+    np.testing.assert_allclose(v, np.asarray(vr), rtol=0, atol=1e-6)
     st.close()
 
 
@@ -312,8 +335,10 @@ def test_sharded_index_int8_matches_flat(tmp_path):
     assert sh.scales is not None and len(sh) == 513
     q = _rows(8, d=64, seed=6)
     vs, is_ = sh.search(q, 5)
-    vf, if_ = DeviceStore(st).search(q, 5)
-    np.testing.assert_allclose(vs, vf, rtol=1e-4, atol=1e-5)
+    # both tiers quantize the query and dequantize in the same order, so
+    # the sharded scan returns the flat kernel layout's exact scores
+    vf, if_ = DeviceStore(st, layout="kernel").search(q, 5)
+    np.testing.assert_array_equal(vs, vf)
     np.testing.assert_array_equal(is_, if_)
     st.close()
 
