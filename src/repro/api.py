@@ -312,9 +312,11 @@ class SystemStats:
     """One accounting view over the whole system: merged runtime counters
     (sequential + batched paths), the store's storage split, which index
     tier is serving, and — when the staged serving pipeline has run — its
-    per-stage queue depth / wait accounting plus hit/miss latency
-    percentiles (``pipeline["stages"]``, ``pipeline["hit"]``,
-    ``pipeline["miss"]``; see ``serving.scheduler.PipelineStats``)."""
+    per-stage queue depth / wait accounting (``pipeline["stages"]``; see
+    ``serving.scheduler.PipelineStats``) and the decode scheduler's
+    counters (``pipeline["decode_slots"]``: waves, admitted, slot wait,
+    length cuts, slot reuse). Latency is per request, in each
+    ``QueryResult.latency_s``."""
     runtime: RuntimeStats
     store_rows: int
     store_bytes: dict

@@ -82,6 +82,11 @@ def _flat_scan_T(q, xT, k):
     return jax.lax.top_k(q @ xT, k)
 
 
+# Profiler span (``jax.profiler.TraceAnnotation``; inert unless a profiler
+# runs) from a flat scan's call to its results on the host: the scan
+# program's queue on the device, its run, and the read back.
+SPAN_SCAN = "storinfer.search.scan"
+
 # rows gathered to the host per DeviceStore.sync step (bounds peak host
 # memory during the initial upload of a paper-scale store)
 _SYNC_ROWS = 65536
@@ -230,17 +235,18 @@ class DeviceStore:
             xT, x, scales = self._xT, self._x, self._scales
         if k > n_rows:
             raise ValueError(f"k={k} exceeds store rows N={n_rows}")
-        if self.layout == "gemm":
-            v, i = _flat_scan_T(jnp.asarray(q), xT, k)
-        elif self.quantized:
-            from repro.kernels.ops import mips_topk_int8
-            q8, qs = quantize_rows(q)
-            v, i = mips_topk_int8(jnp.asarray(q8), jnp.asarray(qs),
-                                  x, scales, k)
-        else:
-            from repro.kernels.ops import mips_topk
-            v, i = mips_topk(jnp.asarray(q), x, k)
-        return np.asarray(v), np.asarray(i)
+        with jax.profiler.TraceAnnotation(SPAN_SCAN):
+            if self.layout == "gemm":
+                v, i = _flat_scan_T(jnp.asarray(q), xT, k)
+            elif self.quantized:
+                from repro.kernels.ops import mips_topk_int8
+                q8, qs = quantize_rows(q)
+                v, i = mips_topk_int8(jnp.asarray(q8), jnp.asarray(qs),
+                                      x, scales, k)
+            else:
+                from repro.kernels.ops import mips_topk
+                v, i = mips_topk(jnp.asarray(q), x, k)
+            return np.asarray(v), np.asarray(i)
 
 
 # One DeviceStore per live store object: index rebuilds (write-backs, tier

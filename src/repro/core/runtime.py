@@ -35,6 +35,8 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import List, Optional, Sequence, Union
 
+from jax.profiler import TraceAnnotation
+
 
 @dataclasses.dataclass
 class QueryResult:
@@ -158,6 +160,11 @@ class StorInferRuntime:
 # Batched serving runtime
 # ---------------------------------------------------------------------------
 
+# Profiler spans (``jax.profiler.TraceAnnotation``; inert unless a
+# profiler runs): the search worker's embedding and the §3.1 write-back.
+SPAN_EMBED = "storinfer.search.embed"
+SPAN_WRITEBACK = "storinfer.writeback"
+
 
 @dataclasses.dataclass
 class BatchedRuntimeCfg:
@@ -255,7 +262,8 @@ class BatchedRuntime:
     # -- the search half (stage 2 of the pipeline) ----------------------------
     def _search_batch(self, texts: List[str]):
         t0 = time.perf_counter()
-        embs = self.embedder.encode(texts)
+        with TraceAnnotation(SPAN_EMBED):
+            embs = self.embedder.encode(texts)
         with self._index_lock:
             index = self.index      # snapshot: rebuilds swap atomically;
         #                             an in-flight search keeps the old one
@@ -356,15 +364,16 @@ class BatchedRuntime:
         flush + rebuild. Called synchronously by ``query_batch`` and from
         the pipeline's background write-back worker."""
         import numpy as np
-        with self._wb_lock:
-            self.store.add_batch(np.asarray(embs), list(texts),
-                                 list(responses))
-            with self._stats_lock:
-                self.stats.writebacks += len(texts)
-            self._pending_writebacks += len(texts)
-            need = self._pending_writebacks >= self.cfg.rebuild_every
-        if need:
-            self.flush_and_rebuild()
+        with TraceAnnotation(SPAN_WRITEBACK):
+            with self._wb_lock:
+                self.store.add_batch(np.asarray(embs), list(texts),
+                                     list(responses))
+                with self._stats_lock:
+                    self.stats.writebacks += len(texts)
+                self._pending_writebacks += len(texts)
+                need = self._pending_writebacks >= self.cfg.rebuild_every
+            if need:
+                self.flush_and_rebuild()
 
     def flush_and_rebuild(self):
         """Persist pending write-backs and rebuild the index over the grown
@@ -416,8 +425,9 @@ class BatchedRuntime:
 
     def pipeline_stats(self) -> Optional[dict]:
         """Snapshot of the staged pipeline's accounting (per-stage queue
-        depth + wait, hit/miss latency percentiles, decode-slot reuse);
-        None if serve() was never started. Survives ``stop_serving``."""
+        depth + wait; decode-slot reuse, slot wait and length cuts); None
+        if serve() was never started. Survives ``stop_serving``. Latency
+        is per request, in each ``QueryResult.latency_s``."""
         p = self._pipeline or self._last_pipeline
         return p.stats_snapshot() if p is not None else None
 

@@ -25,6 +25,14 @@ import numpy as np
 from repro.core.tokenizer import EOS
 from repro.models import model as M
 
+# Profiler spans of the decode worker (``jax.profiler.TraceAnnotation``;
+# inert unless a profiler runs). Admission, the chunk and retirement tile
+# ``BatchScheduler.step_chunk`` with the pipeline's wait span.
+SPAN_ADMIT = "storinfer.decode.admit"
+SPAN_PREFILL = "storinfer.decode.prefill"
+SPAN_CHUNK = "storinfer.decode.chunk"
+SPAN_FINISH = "storinfer.decode.finish"
+
 
 def sample_token(logits, rng, temperature):
     lg = logits.astype(jnp.float32)
@@ -190,6 +198,7 @@ class Request:
     done: bool = False
     cancelled: bool = False
     slot: int = -1
+    t_submit: float = 0.0     # perf_counter stamp at BatchScheduler.submit
     t_done: float = 0.0       # perf_counter stamp when the slot retired
     chunks: int = 0           # decode chunks this request was live for
 
@@ -212,7 +221,9 @@ class BatchScheduler:
     being torn down per admission. ``ServingPipeline``'s decode stage
     keeps one instance alive across every microbatch and feeds misses in
     continuously; ``waves`` / ``admitted`` / ``slot_uses`` account for
-    the reuse."""
+    the reuse, ``slot_wait_s`` (submit to admission, summed over admitted
+    requests) for the wait, and ``len_cuts`` for the waves closed by a
+    prompt-length mismatch while a slot was still free."""
 
     def __init__(self, engine: Engine, batch_size: int = 4):
         self.e = engine
@@ -229,8 +240,11 @@ class BatchScheduler:
         self.waves = 0                      # admission waves opened
         self.admitted = 0                   # requests given a slot, ever
         self.slot_uses = [0] * batch_size   # admissions per slot (reuse)
+        self.slot_wait_s = 0.0              # submit -> admission, summed
+        self.len_cuts = 0                   # waves closed on prompt length
 
     def submit(self, req: Request):
+        req.t_submit = time.perf_counter()
         self.waiting.append(req)
 
     @property
@@ -254,8 +268,12 @@ class BatchScheduler:
                 r.cancelled = True
 
     def _admit(self):
-        if self.live.any():
+        if self.live.any() or not self.waiting:
             return          # wave in flight; next wave starts once it drains
+        with jax.profiler.TraceAnnotation(SPAN_ADMIT):
+            self._admit_wave()
+
+    def _admit_wave(self):
         wave_len = None
         wave_temp = _UNSET = object()
         free = list(range(self.B))
@@ -270,12 +288,14 @@ class BatchScheduler:
             ids = self.e.tok.encode(req.prompt, bos=True)
             ids = ids[: self.e.max_len - req.max_new - 1]
             if wave_len is not None and len(ids) != wave_len:
+                self.len_cuts += 1
                 break       # different prompt length -> opens the next wave
             if wave_temp is not _UNSET and req.temperature != wave_temp:
                 break       # decode runs ONE temperature per chunk, so a
             #                 wave admits only same-temperature requests
             #                 (mixed traffic forms waves, like lengths)
             self.waiting.pop(0)
+            self.slot_wait_s += time.perf_counter() - req.t_submit
             wave_temp = req.temperature
             if wave_len is None:
                 self.waves += 1
@@ -283,11 +303,12 @@ class BatchScheduler:
             slot = free.pop(0)
             self.admitted += 1
             self.slot_uses[slot] += 1
-            tokens = jnp.asarray([ids], jnp.int32)
-            logits, one_cache = self.e._prefill(self.e.params, tokens)
-            self.cache = self.e._write_slot(self.cache, one_cache,
-                                            jnp.asarray(slot, jnp.int32))
-            first = int(jnp.argmax(logits[0, -1]))
+            with jax.profiler.TraceAnnotation(SPAN_PREFILL):
+                tokens = jnp.asarray([ids], jnp.int32)
+                logits, one_cache = self.e._prefill(self.e.params, tokens)
+                self.cache = self.e._write_slot(
+                    self.cache, one_cache, jnp.asarray(slot, jnp.int32))
+                first = int(jnp.argmax(logits[0, -1]))
             req.out_ids.append(first)
             req.slot = slot
             self.token = self.token.at[slot, 0].set(first)
@@ -296,40 +317,43 @@ class BatchScheduler:
             self.cache_len = jnp.asarray(wave_len - 1, jnp.int32)
 
     def _retire(self):
-        for slot in range(self.B):
-            r = self.reqs[slot]
-            if r is None:
-                continue
-            if (r.cancelled or len(r.out_ids) >= r.max_new
-                    or (r.out_ids and r.out_ids[-1] == EOS)):
-                r.done = True
-                r.t_done = time.perf_counter()
-                self.finished.append(r)
-                self.reqs[slot] = None
-                self.live[slot] = False
+        with jax.profiler.TraceAnnotation(SPAN_FINISH):
+            for slot in range(self.B):
+                r = self.reqs[slot]
+                if r is None:
+                    continue
+                if (r.cancelled or len(r.out_ids) >= r.max_new
+                        or (r.out_ids and r.out_ids[-1] == EOS)):
+                    r.done = True
+                    r.t_done = time.perf_counter()
+                    self.finished.append(r)
+                    self.reqs[slot] = None
+                    self.live[slot] = False
 
     def step_chunk(self):
         self._admit()
         self._retire()
         if not self.live.any():
             return False
-        self.rng, sub = jax.random.split(self.rng)
-        temps = [r.temperature for r in self.reqs if r is not None]
-        temp = temps[0] if temps and temps[0] is not None else None
-        self.token, self.cache, self.cache_len, toks = self.e._decode_chunk(
-            self.e.params, self.token, self.cache, self.cache_len + 1, sub,
-            temp, jnp.asarray(self.live))
-        self.cache_len = self.cache_len - 1
-        toks = np.asarray(toks)
-        for slot in range(self.B):
-            r = self.reqs[slot]
-            if r is None:
-                continue
-            r.chunks += 1
-            for t in toks[slot]:
-                if len(r.out_ids) >= r.max_new or t == EOS:
-                    break
-                r.out_ids.append(int(t))
+        with jax.profiler.TraceAnnotation(SPAN_CHUNK):
+            self.rng, sub = jax.random.split(self.rng)
+            temps = [r.temperature for r in self.reqs if r is not None]
+            temp = temps[0] if temps and temps[0] is not None else None
+            self.token, self.cache, self.cache_len, toks = \
+                self.e._decode_chunk(self.e.params, self.token, self.cache,
+                                     self.cache_len + 1, sub, temp,
+                                     jnp.asarray(self.live))
+            self.cache_len = self.cache_len - 1
+            toks = np.asarray(toks)
+            for slot in range(self.B):
+                r = self.reqs[slot]
+                if r is None:
+                    continue
+                r.chunks += 1
+                for t in toks[slot]:
+                    if len(r.out_ids) >= r.max_new or t == EOS:
+                        break
+                    r.out_ids.append(int(t))
         self._retire()
         return True
 

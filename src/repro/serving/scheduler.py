@@ -42,10 +42,13 @@ miss finished) is broken into workers connected by bounded queues:
 Every queue is bounded (``queue_depth``), so a slow stage exerts
 backpressure on its producer instead of buffering unboundedly —
 ``submit`` itself blocks once the admit queue is full.
+
+Each worker marks its steps with ``jax.profiler.TraceAnnotation`` spans
+(``storinfer.<stage>.<step>``; inert unless a profiler runs), so a trace
+puts every idle stretch of the device down to what the host was doing.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import queue
 import threading
@@ -54,6 +57,7 @@ from concurrent.futures import CancelledError, Future, InvalidStateError
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -79,11 +83,6 @@ class Submission:
 class BatcherStats:
     batches: int = 0
     items: int = 0
-    max_batch_seen: int = 0
-
-    @property
-    def mean_batch(self) -> float:
-        return self.items / self.batches if self.batches else 0.0
 
 
 class MicroBatcher:
@@ -206,8 +205,6 @@ class MicroBatcher:
                 continue
             self.stats.batches += 1
             self.stats.items += len(batch)
-            self.stats.max_batch_seen = max(self.stats.max_batch_seen,
-                                            len(batch))
             for s, r in zip(batch, results):
                 s.future.set_result(r)
 
@@ -217,14 +214,12 @@ class MicroBatcher:
 # ---------------------------------------------------------------------------
 
 
-def _pct_ms(lat_s) -> Optional[dict]:
-    """p50/p99/mean (ms) over a latency window; None when empty."""
-    if not lat_s:
-        return None
-    a = np.asarray(lat_s, np.float64) * 1e3
-    return {"n": int(a.size), "p50_ms": float(np.percentile(a, 50)),
-            "p99_ms": float(np.percentile(a, 99)),
-            "mean_ms": float(a.mean())}
+# Profiler spans of the pipeline's workers (the decode worker's admit,
+# chunk and finish spans are in ``serving/engine.py``).
+SPAN_COLLECT = "storinfer.search.collect"
+SPAN_ROUTE = "storinfer.search.route"
+SPAN_GET_PAIR = "storinfer.resolve.get_pair"
+SPAN_DECODE_WAIT = "storinfer.decode.wait"
 
 
 @dataclasses.dataclass
@@ -242,15 +237,13 @@ class StageStats:
 
 
 class PipelineStats:
-    """Thread-safe pipeline accounting: per-stage queue depth + wait, and
-    rolling hit/miss end-to-end latency windows for percentiles."""
+    """Thread-safe pipeline accounting: per-stage queue depth + wait.
+    End-to-end latency is each ``QueryResult.latency_s``."""
 
-    def __init__(self, window: int = 4096):
+    def __init__(self):
         self.stages: Dict[str, StageStats] = {
             "search": StageStats(), "resolve": StageStats(),
             "decode": StageStats(), "writeback": StageStats()}
-        self.hit_lat = collections.deque(maxlen=window)
-        self.miss_lat = collections.deque(maxlen=window)
         self.search_batches = 0
         self.writeback_errors = 0
         self._lock = threading.Lock()
@@ -262,10 +255,6 @@ class PipelineStats:
             st.wait_s += wait_s
             st.max_depth = max(st.max_depth, depth)
 
-    def record_latency(self, hit: bool, latency_s: float):
-        with self._lock:
-            (self.hit_lat if hit else self.miss_lat).append(latency_s)
-
     def snapshot(self, depths: Optional[Dict[str, int]] = None) -> dict:
         """Plain-dict view (the ``SystemStats.pipeline`` payload)."""
         with self._lock:
@@ -276,8 +265,6 @@ class PipelineStats:
                            "max_depth": st.max_depth,
                            "depth": (depths or {}).get(name, 0)}
                     for name, st in self.stages.items()},
-                "hit": _pct_ms(self.hit_lat),
-                "miss": _pct_ms(self.miss_lat),
                 "search_batches": self.search_batches,
                 "writeback_errors": self.writeback_errors,
             }
@@ -442,6 +429,8 @@ class ServingPipeline:
         if sched is not None:
             snap["decode_slots"] = {"slots": sched.B, "waves": sched.waves,
                                     "admitted": sched.admitted,
+                                    "slot_wait_s": sched.slot_wait_s,
+                                    "len_cuts": sched.len_cuts,
                                     "slot_uses": list(sched.slot_uses)}
         return snap
 
@@ -452,6 +441,10 @@ class ServingPipeline:
         of being re-queued — re-putting into the BOUNDED admit queue
         could block forever against producers refilling the freed slots
         (this worker is the queue's only consumer)."""
+        with TraceAnnotation(SPAN_COLLECT):
+            return self._collect_batch()
+
+    def _collect_batch(self) -> List[Submission]:
         first = self._admit_q.get()
         if first is None:
             self._admit_done = True
@@ -495,28 +488,34 @@ class ServingPipeline:
                 for s in batch:
                     _set_future_exception(s.future, e)
                 continue
-            t = time.perf_counter()
-            embs = np.asarray(embs)
-            with self.rt._stats_lock:
-                self.rt.stats.batches += 1
-            with self.stats._lock:
-                self.stats.search_batches += 1
-            s_th = self.rt.cfg.s_th_run
-            for qi, s in enumerate(batch):
-                s.t_search = t
-                s.score = float(scores[qi])
-                s.row = int(rows[qi])
-                s.hit = s.score >= s_th
-                s.t_routed = time.perf_counter()
-                if s.hit or not self._has_decode:
-                    self._resolve_q.put(s)       # stage 3: hit-resolve
-                else:
-                    s.embedding = embs[qi]       # threaded to write-back
-                    self._decode_q.put(s)        # stage 4: decode
+            with TraceAnnotation(SPAN_ROUTE):
+                self._route(batch, scores, rows, embs)
         # shutdown: propagate the sentinel downstream
         self._resolve_q.put(None)
         if self._has_decode:
             self._decode_q.put(None)
+
+    def _route(self, batch, scores, rows, embs):
+        """Hand each searched submission to the resolve (hit) or decode
+        (miss) queue; a full queue blocks here (backpressure)."""
+        t = time.perf_counter()
+        embs = np.asarray(embs)
+        with self.rt._stats_lock:
+            self.rt.stats.batches += 1
+        with self.stats._lock:
+            self.stats.search_batches += 1
+        s_th = self.rt.cfg.s_th_run
+        for qi, s in enumerate(batch):
+            s.t_search = t
+            s.score = float(scores[qi])
+            s.row = int(rows[qi])
+            s.hit = s.score >= s_th
+            s.t_routed = time.perf_counter()
+            if s.hit or not self._has_decode:
+                self._resolve_q.put(s)       # stage 3: hit-resolve
+            else:
+                s.embedding = embs[qi]       # threaded to write-back
+                self._decode_q.put(s)        # stage 4: decode
 
     # -- stage 3: hit-resolve (and engine-less miss resolve) ----------------
     def _resolve_worker(self):
@@ -532,18 +531,19 @@ class ServingPipeline:
             self.stats.record_wait("resolve", now - s.t_routed,
                                    self._resolve_q.qsize() + 1)
             try:
-                if s.hit:
-                    mq, resp = self.rt.store.get_pair(s.row)
-                else:                   # miss with no engine behind it
-                    mq, resp = None, ""
-                done = time.perf_counter()
-                qr = QueryResult(
-                    response=resp, source="store" if s.hit else "llm",
-                    hit=s.hit, score=s.score, matched_query=mq,
-                    search_s=s.t_search - s.t_admit, llm_s=0.0,
-                    latency_s=done - s.t_admit)
-                self._account(qr)
-                s.future.set_result(qr)
+                with TraceAnnotation(SPAN_GET_PAIR):
+                    if s.hit:
+                        mq, resp = self.rt.store.get_pair(s.row)
+                    else:               # miss with no engine behind it
+                        mq, resp = None, ""
+                    done = time.perf_counter()
+                    qr = QueryResult(
+                        response=resp, source="store" if s.hit else "llm",
+                        hit=s.hit, score=s.score, matched_query=mq,
+                        search_s=s.t_search - s.t_admit, llm_s=0.0,
+                        latency_s=done - s.t_admit)
+                    self._account(qr)
+                    s.future.set_result(qr)
             except Exception as e:              # noqa: BLE001
                 _set_future_exception(s.future, e)
 
@@ -567,7 +567,7 @@ class ServingPipeline:
             self._wb_q.put(None)
 
     def _decode_loop(self, pending: Dict[int, "Submission"]):
-        from repro.serving.engine import BatchScheduler, Request
+        from repro.serving.engine import SPAN_FINISH, BatchScheduler, Request
         sched = BatchScheduler(self.rt.engine,
                                batch_size=self.decode_slots)
         self.scheduler = sched
@@ -589,7 +589,8 @@ class ServingPipeline:
             if not pending:
                 if sentinel:
                     break
-                s = self._decode_q.get()     # idle: block for work
+                with TraceAnnotation(SPAN_DECODE_WAIT):
+                    s = self._decode_q.get()     # idle: block for work
                 if s is None:
                     break
                 if self._abort:
@@ -618,8 +619,9 @@ class ServingPipeline:
                 continue
             if pending:
                 sched.step_chunk()           # admit into free slots + decode
-                for r in sched.drain_finished():
-                    self._finish_miss(pending.pop(r.rid), r)
+                with TraceAnnotation(SPAN_FINISH):
+                    for r in sched.drain_finished():
+                        self._finish_miss(pending.pop(r.rid), r)
 
     def _finish_miss(self, s: Submission, req):
         from repro.core.runtime import QueryResult
@@ -686,7 +688,6 @@ class ServingPipeline:
             st.queries += 1
             st.hits += int(qr.hit)
             st.misses += int(not qr.hit)
-        self.stats.record_latency(qr.hit, qr.latency_s)
 
 
 def _cancel_future(f: Future):

@@ -9,6 +9,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
@@ -124,9 +125,11 @@ def test_hit_futures_resolve_before_any_miss(engine_parts, stored):
         assert max(r.latency_s for r in hit_res) \
             < min(r.latency_s for r in miss_res)
 
+        hit_ms = [r.latency_s * 1e3 for r in hit_res]
+        miss_ms = [r.latency_s * 1e3 for r in miss_res]
+        assert len(hit_ms) == 3 and len(miss_ms) == 3
+        assert np.median(hit_ms) < np.median(miss_ms)
         snap = rt.pipeline_stats()
-        assert snap["hit"]["n"] == 3 and snap["miss"]["n"] == 3
-        assert snap["hit"]["p50_ms"] < snap["miss"]["p50_ms"]
         assert snap["stages"]["search"]["items"] == 6
         assert snap["stages"]["decode"]["items"] == 3
     assert rt.stats.queries == 6
@@ -244,6 +247,68 @@ def test_batch_scheduler_temperature_gates_waves(engine_parts):
     assert int(sched.live.sum()) == 1    # greedy wave first, sampled waits
     done = sched.run_to_completion()
     assert len(done) == 2 and sched.waves == 2
+
+
+def test_batch_scheduler_counts_waves_cut_on_prompt_length(engine_parts):
+    """A wave closes at the first waiting request whose prompt length
+    differs while a slot is still free (``len_cuts``); equal lengths fill
+    one wave and cut nothing."""
+    from repro.serving.engine import BatchScheduler, Request
+    eng = make_engine(engine_parts)
+    same = BatchScheduler(eng, batch_size=4)
+    for i in range(3):
+        same.submit(Request(rid=i, prompt=f"same length prompt {i}",
+                            max_new=4))
+    same.run_to_completion()
+    assert same.waves == 1 and same.admitted == 3 and same.len_cuts == 0
+
+    mixed = BatchScheduler(eng, batch_size=4)
+    mixed.submit(Request(rid=0, prompt="a short prompt", max_new=4))
+    mixed.submit(Request(rid=1, prompt="a much longer prompt than the "
+                         "first one was", max_new=4))
+    mixed._admit()
+    assert int(mixed.live.sum()) == 1 and mixed.len_cuts == 1
+    mixed.run_to_completion()
+    assert mixed.waves == 2 and mixed.admitted == 2 and mixed.len_cuts == 1
+
+
+def test_batch_scheduler_slot_wait_grows_while_a_wave_is_in_flight(
+        engine_parts):
+    """``slot_wait_s`` sums each admitted request's time from ``submit``
+    to its slot: a request that arrives behind a live wave waits for it."""
+    from repro.serving.engine import BatchScheduler, Request
+    eng = make_engine(engine_parts)
+    sched = BatchScheduler(eng, batch_size=4)
+    sched.submit(Request(rid=0, prompt="the first wave", max_new=4))
+    sched._admit()
+    alone = sched.slot_wait_s
+    assert sched.admitted == 1 and alone < 0.1
+    sched.submit(Request(rid=1, prompt="the first wave", max_new=4))
+    sched._admit()                       # the wave is live: no admission
+    assert sched.admitted == 1
+    time.sleep(0.2)
+    sched.run_to_completion()
+    assert sched.admitted == 2 and sched.waves == 2
+    assert sched.slot_wait_s - alone >= 0.2
+
+
+def test_pipeline_snapshot_carries_the_decode_counters(engine_parts,
+                                                       stored):
+    """``stats_snapshot()["decode_slots"]`` exposes the slot wait and the
+    length cuts beside ``waves`` and ``admitted``."""
+    emb, store, qs, rs = stored
+    eng = make_engine(engine_parts)
+    with BatchedRuntime.from_store(
+            store, emb, engine=eng,
+            cfg=BatchedRuntimeCfg(max_wait_s=0.005, decode_slots=4)) as rt:
+        futs = [rt.submit(p, max_new=4) for p in
+                ("a short novel zebra", "a much longer novel zebra prompt "
+                 "than the one before it", "a short novel yak")]
+        assert all(not f.result(timeout=300).hit for f in futs)
+        slots = rt.pipeline_stats()["decode_slots"]
+    assert slots["admitted"] == 3 and slots["waves"] >= 2
+    assert slots["slot_wait_s"] > 0.0
+    assert 1 <= slots["len_cuts"] <= slots["waves"]
 
 
 def test_submit_temperature_reaches_decode(engine_parts, stored):
