@@ -369,7 +369,6 @@ def _tokenizer_from_store(store, sample: int = 512):
 
 def _build_engine(ecfg: EngineCfg, tokenizer):
     import jax
-    import jax.numpy as jnp
 
     from repro.configs import get_config, reduced
     from repro.models import model as M
@@ -379,11 +378,11 @@ def _build_engine(ecfg: EngineCfg, tokenizer):
         cfg = dataclasses.replace(reduced(cfg),
                                   vocab_size=tokenizer.vocab_size,
                                   n_layers=ecfg.smoke_layers)
-    params = M.init_model(jax.random.PRNGKey(ecfg.seed), cfg,
-                          dtype=jnp.float32)
-    return Engine(cfg, params, tokenizer,
-                  M.RunCfg(attn_impl="naive", remat=False),
-                  max_len=ecfg.max_len, chunk=ecfg.chunk)
+    # params at the configuration's dtype; the engine's run keeps the
+    # residual stream in f32
+    params = M.init_model(jax.random.PRNGKey(ecfg.seed), cfg)
+    return Engine(cfg, params, tokenizer, max_len=ecfg.max_len,
+                  chunk=ecfg.chunk)
 
 
 # ---------------------------------------------------------------------------

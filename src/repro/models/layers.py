@@ -28,8 +28,27 @@ def dense_init(key, d_in, d_out, dtype, bias=False, scale=None):
     return p
 
 
+def _against(x, w):
+    """The activation operand of a matmul of ``x`` against weight ``w``,
+    and the dtype to accumulate in. A weight narrower than the activation
+    (bf16 params under an f32 residual stream) takes the activation in its
+    own dtype and accumulates in the activation's: the one bf16 MXU pass
+    that the TPU's default precision makes of an f32 matmul, without
+    converting the weight. Otherwise jnp's promotion (None)."""
+    if jnp.dtype(w.dtype).itemsize < jnp.dtype(x.dtype).itemsize:
+        return x.astype(w.dtype), x.dtype
+    return x, None
+
+
+def weight_einsum(spec, x, w):
+    """``einsum(spec, x, w)`` against a weight, under ``_against``'s rule."""
+    x, acc = _against(x, w)
+    return jnp.einsum(spec, x, w, preferred_element_type=acc)
+
+
 def dense(p, x):
-    y = x @ p["w"]
+    xw, acc = _against(x, p["w"])
+    y = jnp.matmul(xw, p["w"], preferred_element_type=acc)
     if "b" in p:
         y = y + p["b"]
     return y

@@ -1,9 +1,10 @@
-"""Multi-head Latent Attention (DeepSeek-V2), with weight-absorbed decode.
+"""Multi-head Latent Attention (DeepSeek-V2): params and projections.
 
-Prefill/train: expand the compressed c_kv back to per-head K/V (naive path).
-Decode: absorb W_uk into the query and attend directly over the compressed
-cache (c_kv ‖ k_rope) — per-token cost O(T·(r + d_rope)·H) instead of
-O(T·(d_nope+d_rope)·H + T·r·H·d), the trick that makes MLA serve-efficient.
+The attention itself is ``model.mla_fullseq`` (train/prefill: expand the
+compressed c_kv back to per-head K/V) and ``model.mla_decode`` (absorb W_uk
+into the query and attend directly over the compressed cache (c_kv ‖
+k_rope) — per-token cost O(T·(r + d_rope)·H) instead of
+O(T·(d_nope+d_rope)·H + T·r·H·d), the trick that makes MLA serve-efficient).
 The cache stores only (c_kv: r, k_rope: d_rope) per token (576 for V2-Lite).
 """
 from __future__ import annotations
@@ -12,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import (dense, dense_init, rmsnorm, rmsnorm_init,
-                                 apply_rope, causal_mask)
+                                 apply_rope)
 
 
 def mla_init(key, cfg, dtype=None):
@@ -51,56 +52,3 @@ def _project_ckv(cfg, p, x, positions):
     krope = dkv[..., None, r:]  # single shared rope head
     krope = apply_rope(krope, positions, cfg.rope_theta)
     return ckv, krope
-
-
-def mla_attention(cfg, p, x, positions, *, mask_offset=0):
-    """Train/prefill path: expand compressed KV to per-head K/V."""
-    B, S, _ = x.shape
-    H = cfg.n_heads
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    qn, qr = _project_q(cfg, p, x)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
-    ckv, krope = _project_ckv(cfg, p, x, positions)
-    kn = jnp.einsum("bsr,rhn->bshn", ckv, p["wuk"])
-    v = jnp.einsum("bsr,rhn->bshn", ckv, p["wuv"])
-    scale = (nope + rope_d) ** -0.5
-    mask = causal_mask(S, S, mask_offset)[:, 0]  # (1,1,S,T)
-    logits = (jnp.einsum("bshn,bthn->bhst", qn, kn)
-              + jnp.einsum("bshr,btr->bhst", qr, krope[:, :, 0, :]))
-    logits = logits.astype(jnp.float32) * scale
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, -1).astype(v.dtype)
-    out = jnp.einsum("bhst,bthv->bshv", probs, v)
-    out_kv = {"ckv": ckv, "krope": krope[:, :, 0, :]}
-    return dense(p["wo"], out.reshape(B, S, H * vd)), out_kv
-
-
-def mla_decode(cfg, p, x, cache, cache_len, positions):
-    """Absorbed decode: attend over the compressed cache directly.
-
-    cache: {"ckv": (B, Smax, r), "krope": (B, Smax, rope_d)}.
-    """
-    B, S, _ = x.shape  # S == 1
-    H = cfg.n_heads
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    qn, qr = _project_q(cfg, p, x)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
-    ckv_new, krope_new = _project_ckv(cfg, p, x, positions)
-    ckv = jax.lax.dynamic_update_slice(cache["ckv"], ckv_new, (0, cache_len, 0))
-    krope = jax.lax.dynamic_update_slice(
-        cache["krope"], krope_new[:, :, 0, :], (0, cache_len, 0))
-    # absorb W_uk into the query: q_c = qn @ W_uk^T  -> (B,S,H,r)
-    q_c = jnp.einsum("bshn,rhn->bshr", qn, p["wuk"])
-    scale = (nope + rope_d) ** -0.5
-    T = ckv.shape[1]
-    logits = (jnp.einsum("bshr,btr->bhst", q_c, ckv)
-              + jnp.einsum("bshr,btr->bhst", qr, krope))
-    logits = logits.astype(jnp.float32) * scale
-    mask = (jnp.arange(T)[None, :] <= cache_len)[None, None, None, :]
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, -1).astype(ckv.dtype)
-    # attend over compressed values then expand once: (B,S,H,r) @ W_uv
-    out_c = jnp.einsum("bhst,btr->bshr", probs, ckv)
-    out = jnp.einsum("bshr,rhv->bshv", out_c, p["wuv"])
-    new_cache = {"ckv": ckv, "krope": krope}
-    return dense(p["wo"], out.reshape(B, S, H * vd)), new_cache

@@ -59,7 +59,10 @@ class RunCfg:
     seq_axis: str = "model"
     batch_axes: Tuple[str, ...] = ("data",)
     aux_coef: float = 0.01
-    logits_f32: bool = False          # cast logits to f32 (loss is f32 anyway)
+    stream_dtype: Any = None          # residual stream dtype (None: the
+                                      # params'); serving keeps f32 over
+                                      # bf16 params, which every matmul
+                                      # then takes in bf16 (Lyr.dense)
     heads_sharded: bool = False       # q-heads TP-shard over "model"
     repeat_kv: bool = False           # Megatron-GQA: kv replicated+repeated
     ssm_chunk: int = 0                # override cfg.ssm_chunk (0 = cfg's);
@@ -252,8 +255,8 @@ def mla_fullseq(cfg, run, p, x, positions, *, mask_offset=0):
     qn, qr = Mla._project_q(cfg, p, x)
     qr = Lyr.apply_rope(qr, positions, cfg.rope_theta)
     ckv, krope = Mla._project_ckv(cfg, p, x, positions)
-    kn = jnp.einsum("bsr,rhn->bshn", ckv, p["wuk"])
-    v = jnp.einsum("bsr,rhv->bshv", ckv, p["wuv"])
+    kn = Lyr.weight_einsum("bsr,rhn->bshn", ckv, p["wuk"])
+    v = Lyr.weight_einsum("bsr,rhv->bshv", ckv, p["wuv"])
     q = jnp.concatenate([qn, qr], axis=-1)                     # (B,S,H,nope+rd)
     kr = jnp.broadcast_to(krope, (B, S, H, rd))
     k = jnp.concatenate([kn, kr], axis=-1)
@@ -330,7 +333,7 @@ def mla_decode(cfg, run, p, x, ckv_c, krope_c, cache_len):
     qn, qr = Mla._project_q(cfg, p, x)
     qr = Lyr.apply_rope(qr, positions, cfg.rope_theta)
     ckv_new, krope_new = Mla._project_ckv(cfg, p, x, positions)
-    q_c = jnp.einsum("bshn,rhn->bshr", qn, p["wuk"])
+    q_c = Lyr.weight_einsum("bshn,rhn->bshr", qn, p["wuk"])
     scale = (nope + rd) ** -0.5
 
     if run.decode_attn == "seq_sharded" and run.mesh is not None:
@@ -350,7 +353,7 @@ def mla_decode(cfg, run, p, x, ckv_c, krope_c, cache_len):
         logits = jnp.where(mask, logits * scale, -1e30)
         probs = jax.nn.softmax(logits, -1).astype(ckv_c.dtype)
         out_c = jnp.einsum("bhst,btr->bshr", probs, ckv_c)
-    out = jnp.einsum("bshr,rhv->bshv", out_c, p["wuv"])
+    out = Lyr.weight_einsum("bshr,rhv->bshv", out_c, p["wuv"])
     return Lyr.dense(p["wo"], out.reshape(B, 1, H * vd)), ckv_c, krope_c
 
 
@@ -468,7 +471,8 @@ def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
 
 
 def embed_tokens(cfg, params, tokens, dtype=None):
-    return jnp.take(params["embed"]["w"], tokens, axis=0)
+    x = jnp.take(params["embed"]["w"], tokens, axis=0)
+    return x if dtype is None else x.astype(dtype)
 
 
 def _constrain(x, run, *spec):
@@ -495,7 +499,7 @@ def _constrain(x, run, *spec):
 def lm_logits(cfg, run, params, x):
     x = Lyr.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
-        logits = x @ params["embed"]["w"].T
+        logits = Lyr.weight_einsum("...d,vd->...v", x, params["embed"]["w"])
     else:
         logits = Lyr.dense(params["lm_head"], x)
     if cfg.padded_vocab != cfg.vocab_size:   # mask Megatron vocab padding
@@ -503,8 +507,7 @@ def lm_logits(cfg, run, params, x):
         logits = jnp.where(pad_mask, logits, -1e30)
     # keep the vocab dim model-sharded: without this GSPMD tends to gather
     # the full (B,S,V) logits per device (tens of GB at 1M tokens).
-    logits = _constrain(logits, run, run.batch_axes, None, "model")
-    return logits.astype(jnp.float32) if run.logits_f32 else logits
+    return _constrain(logits, run, run.batch_axes, None, "model")
 
 
 # ---------------------------------------------------------------------------
@@ -625,7 +628,7 @@ def forward(cfg, params, batch, run=RunCfg()):
     """Full-sequence forward. Returns (logits (B,S,V), aux dict)."""
     tokens = batch["tokens"]
     positions = _positions(batch, tokens)
-    x = embed_tokens(cfg, params, tokens)
+    x = embed_tokens(cfg, params, tokens, run.stream_dtype)
     aux = jnp.zeros((), jnp.float32)
     mrope = batch.get("mrope_positions")
 
@@ -678,7 +681,7 @@ def prefill(cfg, params, batch, run=RunCfg(), max_len=None):
     B, S = tokens.shape
     max_len = max_len or S
     positions = _positions(batch, tokens)
-    x = embed_tokens(cfg, params, tokens)
+    x = embed_tokens(cfg, params, tokens, run.stream_dtype)
     mrope = batch.get("mrope_positions")
 
     if cfg.is_encoder_decoder:
@@ -779,7 +782,7 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
 
     Returns (logits (B,1,V), new_cache).
     """
-    x = embed_tokens(cfg, params, token)
+    x = embed_tokens(cfg, params, token, run.stream_dtype)
     kind = main_block_kind(cfg)
 
     if cfg.is_encoder_decoder:

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init, mlp_init, mlp
+from repro.models.layers import dense_init, mlp, mlp_init, weight_einsum
 
 
 def moe_init(key, cfg, dtype=None):
@@ -85,9 +85,9 @@ def dispatch_slots(cfg, idx, n_tokens):
 
 def expert_ffn(cfg, experts, buf):
     """buf: (E, C, d) -> (E, C, d) through gated-SiLU expert MLPs."""
-    h1 = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, experts["w1"]))
-    h3 = jnp.einsum("ecd,edf->ecf", buf, experts["w3"])
-    return jnp.einsum("ecf,efd->ecd", h1 * h3, experts["w2"])
+    h1 = jax.nn.silu(weight_einsum("ecd,edf->ecf", buf, experts["w1"]))
+    h3 = weight_einsum("ecd,edf->ecf", buf, experts["w3"])
+    return weight_einsum("ecf,efd->ecd", h1 * h3, experts["w2"])
 
 
 def moe_ffn(cfg, p, x):

@@ -9,7 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense, dense_init, rmsnorm_init, gated_rmsnorm
+from repro.models.layers import (dense, dense_init, gated_rmsnorm,
+                                 rmsnorm_init, weight_einsum)
 
 
 # ---------------------------------------------------------------------------
@@ -175,9 +176,11 @@ def ssm_decode(cfg, p, x, state, conv_state):
     di = cfg.d_inner_ssm
     W = cfg.ssm_conv
     z, xBC, dt = _split_in_proj(cfg, dense(p["in_proj"], x))
-    # conv over (conv_state ++ xBC)
-    window = jnp.concatenate([conv_state, xBC], axis=1)      # (B, W, C)
-    conv = jnp.einsum("bwc,wc->bc", window, p["conv_w"]) + p["conv_b"]
+    # conv over (conv_state ++ xBC), in the cache's dtype: the window's
+    # tail is the next conv state
+    window = jnp.concatenate([conv_state, xBC.astype(conv_state.dtype)],
+                             axis=1)                          # (B, W, C)
+    conv = weight_einsum("bwc,wc->bc", window, p["conv_w"]) + p["conv_b"]
     xBC = jax.nn.silu(conv)[:, None, :]
     new_conv_state = window[:, 1:, :]
     xs = xBC[..., :di].reshape(B, H, P)

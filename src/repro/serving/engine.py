@@ -42,6 +42,13 @@ def sample_token(logits, rng, temperature):
     return jax.random.categorical(rng, lg / t, axis=-1)
 
 
+# The engine's run: the residual stream, norms, softmax and logits in f32
+# over params held at the configuration's dtype; each matmul takes a bf16
+# weight as it is, with its activation in bf16, accumulating in f32.
+SERVE_RUN = M.RunCfg(attn_impl="naive", remat=False,
+                     stream_dtype=jnp.float32)
+
+
 class Engine:
     """One model, jit'd once; serves many sessions."""
 
@@ -50,7 +57,7 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.tok = tokenizer
-        self.run = run or M.RunCfg(attn_impl="naive", remat=False)
+        self.run = run or SERVE_RUN
         self.max_len = max_len
         self.chunk = chunk
         self._prefill = jax.jit(self._prefill_impl)

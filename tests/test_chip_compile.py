@@ -73,8 +73,9 @@ def test_float_mips_kernel_compiles_at_f32_residency(one_chip, Q, k):
 
 @pytest.fixture(scope="module")
 def engine_shapes(one_chip):
-    """qwen3-1.7b at full width as the facade builds it (f32 params,
-    EngineCfg's max_len/chunk, the pipeline's 4 decode slots)."""
+    """qwen3-1.7b at full width as the facade builds it (params at the
+    configuration's dtype, the engine's run, EngineCfg's max_len/chunk,
+    the pipeline's 4 decode slots)."""
     from repro.api import EngineCfg
     from repro.configs import get_config
     from repro.core.tokenizer import Tokenizer
@@ -82,13 +83,11 @@ def engine_shapes(one_chip):
     from repro.serving.engine import Engine
     ecfg, slots = EngineCfg(), 4
     cfg = get_config(ecfg.arch)
-    eng = Engine(cfg, None, Tokenizer(["x"]),
-                 M.RunCfg(attn_impl="naive", remat=False),
-                 max_len=ecfg.max_len, chunk=ecfg.chunk)
+    eng = Engine(cfg, None, Tokenizer(["x"]), max_len=ecfg.max_len,
+                 chunk=ecfg.chunk)
     place = lambda s: _sds(s.shape, s.dtype, one_chip)    # noqa: E731
     params = jax.tree_util.tree_map(place, jax.eval_shape(
-        lambda: M.init_model(jax.random.PRNGKey(0), cfg,
-                             dtype=jnp.float32)))
+        lambda: M.init_model(jax.random.PRNGKey(0), cfg)))
     cache = jax.tree_util.tree_map(
         place, M.cache_struct(cfg, slots, ecfg.max_len))
     return eng, params, cache, slots
@@ -100,13 +99,30 @@ def _fits_one_chip(compiled):
     assert used < HBM_BYTES, used
 
 
-def test_engine_decode_chunk_compiles_full_width(engine_shapes, one_chip):
-    eng, params, cache, B = engine_shapes
-    c = eng._decode_chunk.lower(
+def _decode_chunk(eng, params, cache, B, one_chip):
+    return eng._decode_chunk.lower(
         params, _sds((B, 1), jnp.int32, one_chip), cache,
         _sds((), jnp.int32, one_chip), _sds((2,), jnp.uint32, one_chip),
         None, _sds((B,), jnp.bool_, one_chip)).compile()
-    _fits_one_chip(c)
+
+
+def test_engine_decode_chunk_compiles_full_width(engine_shapes, one_chip):
+    eng, params, cache, B = engine_shapes
+    _fits_one_chip(_decode_chunk(eng, params, cache, B, one_chip))
+
+
+def test_engine_decode_chunk_takes_bf16_weights_at_16_slots(engine_shapes,
+                                                             one_chip):
+    """The benchmark's 16 slots: bf16 weights (3.44 GB) and the cache,
+    where f32 weights alone are 6.9 GB."""
+    from repro.models import model as M
+    eng, params, _, _ = engine_shapes
+    B = 16
+    cache = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        M.cache_struct(eng.cfg, B, eng.max_len))
+    c = _decode_chunk(eng, params, cache, B, one_chip)
+    assert c.memory_analysis().argument_size_in_bytes < 4.5e9
 
 
 def test_engine_prefill_compiles_full_width(engine_shapes, one_chip):
