@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed and the program is freed, the requests the
-window finished are judged by the plain reference in ``reference.py``:
+window finished are judged by the plain reference (``reference.py``, and
+the configuration's architecture module for the model):
 every route, and a sample drawn from the seed of the answers (hits up to
 the mix's count; misses with the longest among them):
 
@@ -67,11 +68,11 @@ def served_ids(rec) -> list:
     return ids + [R.EOS] if len(ids) < rec.req.max_new else ids
 
 
-def judge(cfg: dict, mix: dict, seed: int, recs, ref_store: RefStore,
+def judge(cfg: dict, arch, mix: dict, seed: int, recs, ref_store: RefStore,
           vocab: dict, weights_seed: int, *, control: bool = False) -> dict:
-    """The numbers compared, each with its limit; with ``control`` the
-    logit gap is the float8 control's and ``program_logit_gap`` the
-    program's."""
+    """The numbers compared, each with its limit, the model's by the
+    architecture module ``arch``; with ``control`` the logit gap is the
+    float8 control's and ``program_logit_gap`` the program's."""
     ok = [r for r in recs if r.done and r.error is None]
     hits = [r for r in ok if r.result.hit]
     misses = [r for r in ok if not r.result.hit]
@@ -98,8 +99,9 @@ def judge(cfg: dict, mix: dict, seed: int, recs, ref_store: RefStore,
         room = cfg["serving"]["max_len"] - 1
         seqs = [(R.encode(r.req.text, vocab)[:room - r.req.max_new],
                  served_ids(r)) for r in miss_s]
-        w = R.init_weights(cfg, weights_seed)
-        prog, ctrl = R.served_gaps(cfg, w, seqs, cfg["serving"]["max_len"],
+        w = arch.init_weights(cfg, weights_seed)
+        prog, ctrl = R.served_gaps(cfg, arch.forward, w, seqs,
+                                   cfg["serving"]["max_len"],
                                    control=control)
         del w
         gap = float(max(g.max() for g in prog))

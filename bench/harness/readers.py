@@ -2,7 +2,8 @@
 counter deltas over the window, the window's finished misses, and device
 time per decode step.
 
-A reader gets ``ctx`` with the run's configuration (``cfg``), traffic mix
+A reader gets ``ctx`` with the run's configuration (``cfg``), its
+architecture module (``arch``, which counts the model's work), traffic mix
 (``mix``), request records (``recs``), window bounds (``t0``, ``t_end``,
 ``window_s``), the drain deadline (``deadline``), the pipeline counters
 before and after the window (``snap0``, ``snap1``), the reduced trace
@@ -12,8 +13,6 @@ store's row count (``store_rows``).
 from __future__ import annotations
 
 import numpy as np
-
-from . import costs
 
 DECODE_PROGRAM = "decode_chunk"
 SCAN_PROGRAM = "mips_topk_int8"
@@ -66,7 +65,7 @@ def token_positions(ctx):
 
 
 def model_flops(ctx) -> float:
-    return float(sum(costs.flops_per_token(ctx.cfg, p)
+    return float(sum(ctx.arch.flops_per_token(ctx.cfg, p)
                      for p in token_positions(ctx)))
 
 

@@ -5,13 +5,17 @@ in straightforward numpy and ``jax.numpy``:
 
 * the hash embedder and the symmetric per-row int8 quantizer the
   configuration's store is defined by, and an exact int8 MIPS scan;
-* the decoder-only transformer the configuration names, in float32 at
-  ``highest`` matmul precision, with its weights made from the run's seed
-  by the published initialisation (normal, fan-in scaled, the same key
-  schedule), so the reference holds its own copy of the weights;
-* the control: the same forward with every linear layer's operands cast to
-  float8 (e4m3, per-row and per-column scales), the step below the stated
-  bfloat16 that a later change might take.
+* the pieces the architecture modules (``bench/arch/<model_type>.py``)
+  build their model from: seeded normal weights, RMSNorm, RoPE, and the
+  linear layer with its control, every operand cast to float8 (e4m3,
+  per-row and per-column scales), the step below the stated bfloat16
+  that a later change might take;
+* ``served_gaps``, which runs an architecture's float32 forward at
+  ``highest`` matmul precision over the served sequences.
+
+The model itself, with its weights made from the run's seed by the
+published initialisation so that the reference holds its own copy, is
+the architecture module's (``spec.load_arch``).
 """
 from __future__ import annotations
 
@@ -23,8 +27,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .costs import model_shapes
 
 _WORDS = re.compile(r"\w+")
 _SPLIT = re.compile(r"\w+|[^\w\s]")
@@ -95,7 +97,7 @@ def encode(text: str, vocab: dict) -> list:
 
 
 # ---------------------------------------------------------------------------
-# model: weights from the seed
+# model: what the architecture modules share
 # ---------------------------------------------------------------------------
 
 
@@ -105,45 +107,6 @@ def padded_vocab(vocab: int) -> int:
 
 def _normal(key, shape, scale):
     return jax.random.normal(key, shape, jnp.float32) * scale
-
-
-def _layer(key, s):
-    d, hd, h, hkv, ff = s["d"], s["hd"], s["h"], s["hkv"], s["ff"]
-    k_attn, k_mlp = jax.random.split(key, 6)[:2]
-    ka = jax.random.split(k_attn, 4)
-    km = jax.random.split(k_mlp, 3)
-    p = {"wq": _normal(ka[0], (d, h * hd), d ** -0.5),
-         "wk": _normal(ka[1], (d, hkv * hd), d ** -0.5),
-         "wv": _normal(ka[2], (d, hkv * hd), d ** -0.5),
-         "wo": _normal(ka[3], (h * hd, d), (h * hd) ** -0.5),
-         "w1": _normal(km[0], (d, ff), d ** -0.5),
-         "w2": _normal(km[1], (ff, d), ff ** -0.5),
-         "w3": _normal(km[2], (d, ff), d ** -0.5)}
-    return p
-
-
-def init_weights(cfg: dict, seed: int) -> dict:
-    """The model's float32 weights from ``seed`` (below 2**31), made on the
-    device in one call. Norm scales are ones at initialisation."""
-    return _init(_frozen(cfg), jnp.asarray(seed, jnp.int32))
-
-
-@partial(jax.jit, static_argnums=(0,))
-def _init(cfg_items, seed):
-    s = model_shapes(dict(cfg_items))
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    vp = padded_vocab(s["vocab"])
-    w = {"embed": _normal(ks[0], (vp, s["d"]), s["d"] ** -0.5)}
-    if not s["tied"]:
-        w["head"] = _normal(ks[1], (s["d"], vp), s["d"] ** -0.5)
-    w["layers"] = jax.vmap(lambda k: _layer(k, s))(
-        jax.random.split(ks[2], s["layers"]))
-    return w
-
-
-# ---------------------------------------------------------------------------
-# model: forward
-# ---------------------------------------------------------------------------
 
 
 def _q8(x, axis):
@@ -173,47 +136,8 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def forward(cfg: dict, w: dict, tokens, fp8: bool = False):
-    """Logits (B, S, vocab) of a causal pass over ``tokens`` (B, S)."""
-    s = model_shapes(cfg)
-    eps = cfg["rms_norm_eps"]
-    B, S = tokens.shape
-    H, Hkv, hd = s["h"], s["hkv"], s["hd"]
-    G = H // Hkv
-    pos = jnp.arange(S)
-    emb = _q8(w["embed"], -1) if fp8 else w["embed"]
-    x = emb[tokens]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-
-    def layer(x, p):
-        h = _rms(x, eps)
-        q = _linear(h, p["wq"], fp8)
-        k = _linear(h, p["wk"], fp8)
-        v = _linear(h, p["wv"], fp8)
-        q = q.reshape(B, S, H, hd)
-        k = k.reshape(B, S, Hkv, hd)
-        v = v.reshape(B, S, Hkv, hd)
-        if cfg.get("qk_norm"):
-            q, k = _rms(q, eps), _rms(k, eps)
-        q = _rope(q, pos, cfg["rope_theta"])
-        k = _rope(k, pos, cfg["rope_theta"])
-        qg = q.reshape(B, S, Hkv, G, hd)
-        a = jnp.einsum("bskgd,btkd->bkgst", qg, k) * hd ** -0.5
-        a = jax.nn.softmax(jnp.where(causal, a, -1e30), axis=-1)
-        o = jnp.einsum("bkgst,btkd->bskgd", a, v).reshape(B, S, H * hd)
-        x = x + _linear(o, p["wo"], fp8)
-        h = _rms(x, eps)
-        u = jax.nn.silu(_linear(h, p["w1"], fp8)) * _linear(h, p["w3"], fp8)
-        return x + _linear(u, p["w2"], fp8), None
-
-    x, _ = jax.lax.scan(layer, x, w["layers"])
-    x = _rms(x, eps)
-    head = w["embed"].T if s["tied"] else w["head"]
-    return _linear(x, head, fp8)[..., :s["vocab"]]
-
-
-@partial(jax.jit, static_argnums=(0, 3))
-def _logits(cfg_items, w, tokens, fp8):
+@partial(jax.jit, static_argnums=(0, 1, 4))
+def _logits(forward, cfg_items, w, tokens, fp8):
     return forward(dict(cfg_items), w, tokens, fp8)
 
 
@@ -223,9 +147,10 @@ def _frozen(cfg: dict) -> tuple:
                         or v is None))
 
 
-def served_gaps(cfg: dict, w: dict, seqs, pad_to: int, *,
+def served_gaps(cfg: dict, forward, w: dict, seqs, pad_to: int, *,
                 control: bool = False, batch: int = 8):
-    """For each (prompt ids, served ids) pair: at every served position the
+    """For each (prompt ids, served ids) pair, under the architecture's
+    ``forward(cfg, w, tokens, fp8)``: at every served position the
     gap by which the served token's reference logit lies below the
     reference's best and, with ``control``, the same gap for the token the
     float8 control puts first. Returns (program gaps, control gaps or
@@ -244,9 +169,11 @@ def served_gaps(cfg: dict, w: dict, seqs, pad_to: int, *,
             for i, (prompt, served) in enumerate(chunk):
                 ids = list(prompt) + list(served[:-1])
                 toks[i, :len(ids)] = ids
-            ref = np.asarray(_logits(items, w, jnp.asarray(toks), False))
+            ref = np.asarray(_logits(forward, items, w, jnp.asarray(toks),
+                                     False))
             if control:
-                low = np.asarray(_logits(items, w, jnp.asarray(toks), True))
+                low = np.asarray(_logits(forward, items, w,
+                                         jnp.asarray(toks), True))
             for i, (prompt, served) in enumerate(chunk):
                 at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
                 r = ref[i, at]

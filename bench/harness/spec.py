@@ -2,7 +2,8 @@
 metrics it reports, all found by name from ``BENCHMARK.json``.
 
 Nothing here knows a particular cell. A configuration is the JSON file that
-its ``BENCHMARK.json`` entry names; a traffic mix is
+its ``BENCHMARK.json`` entry names, and its architecture the module
+``bench/arch/<model_type>.py`` (see ``load_arch``); a traffic mix is
 ``bench/traffic/<mix>.json``; a per-layer metric is the reader
 ``bench/metrics/<metric>.py`` (see ``load_reader``). Adding any of them
 is adding files and entries.
@@ -12,10 +13,15 @@ from __future__ import annotations
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 BENCH_DIR = "bench"
 _E2E = re.compile(r"^(hit|miss|all)_p(\d+(?:\.\d+)?)_ms$")
+_ARCH_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+# what an architecture module provides (see bench/arch/qwen3.py)
+ARCH_API = ("program_widths", "init_weights", "forward", "param_count",
+            "kv_bytes_per_position", "flops_per_token", "decode_step_cost")
 
 
 class SpecError(ValueError):
@@ -97,12 +103,39 @@ def load_reader(root: Path, name: str):
     if not path.is_file():
         raise SpecError(f"per-layer metric {name!r}: {metrics / name}.py "
                         "not found")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", name), path)
+    return _exec(path, "bench_metric_" + re.sub(r"\W", "_", name),
+                 ("read",))
+
+
+def load_arch(root: Path, cfg: dict):
+    """The architecture module ``bench/arch/<model_type>.py`` that the
+    configuration names by its ``model_type``: the program widths the file
+    states, the plain reference and its control, and the counts of the
+    stated work (``ARCH_API``). A module may reuse another's functions by
+    loading it here. Each file is executed once a process, so the
+    reference's compiled programs are kept from run to run."""
+    kind = cfg.get("model_type")
+    path = Path(root) / BENCH_DIR / "arch" / f"{kind}.py"
+    if not (isinstance(kind, str) and _ARCH_NAME.match(kind)
+            and path.is_file()):
+        raise SpecError(f"{cfg.get('name')}: model_type {kind!r}: {path} "
+                        "not found")
+    name = "bench_arch_" + re.sub(r"\W", "_", kind)
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != str(path):
+        mod = sys.modules[name] = _exec(path, name, ARCH_API)
+    return mod
+
+
+def _exec(path: Path, name: str, required: tuple):
+    """Execute the file ``path`` as the module ``name``; it has to define
+    each function in ``required``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    if not callable(getattr(mod, "read", None)):
-        raise SpecError(f"{path} has no read(ctx)")
+    missing = [f for f in required if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"{path} lacks {missing}")
     return mod
 
 

@@ -87,29 +87,17 @@ def ensure_store(root: Path, cfg: dict, pairs, tok, log) -> Path:
     return path
 
 
-def register_model(cfg: dict) -> str:
+def register_model(cfg: dict, arch) -> str:
     """Register the configuration's model under its own name: the
     program's architecture with the file's overrides (a depth cut), after
-    checking that every stated width agrees with the program's config."""
+    checking that every width the file states, as the architecture module
+    ``arch`` maps it onto the program's config, agrees with the program."""
     from repro.configs.base import get_config, register
     base = get_config(cfg["program_arch"])
     mc = dataclasses.replace(base, name=cfg["name"],
                              **cfg.get("program_overrides", {}))
-    want = {"d_model": cfg["hidden_size"],
-            "n_heads": cfg["num_attention_heads"],
-            "n_kv_heads": cfg["num_key_value_heads"],
-            "d_ff": cfg["intermediate_size"],
-            "vocab_size": cfg["vocab_size"],
-            "n_layers": cfg["num_hidden_layers"],
-            "resolved_head_dim": cfg["head_dim"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "attn_bias": cfg["attention_bias"],
-            "qk_norm": cfg["qk_norm"],
-            "gated_mlp": cfg["hidden_act"] == "silu",
-            "rope_theta": cfg["rope_theta"],
-            "norm_eps": cfg["rms_norm_eps"],
-            "dtype": cfg["torch_dtype"]}
-    bad = {k: (getattr(mc, k), v) for k, v in want.items()
+    stated = arch.program_widths(cfg)
+    bad = {k: (getattr(mc, k), v) for k, v in stated.items()
            if getattr(mc, k) != v}
     if bad:
         raise ValueError(f"{cfg['name']}: the program's {cfg['program_arch']}"
@@ -118,15 +106,16 @@ def register_model(cfg: dict) -> str:
     return mc.name
 
 
-def open_system(root: Path, cfg: dict, store: Path, tok, weights_seed: int):
+def open_system(root: Path, cfg: dict, arch, store: Path, tok,
+                weights_seed: int):
     from repro.api import EngineCfg, StorInfer, SystemCfg
     from repro.core.runtime import BatchedRuntimeCfg
     sv = cfg["serving"]
-    arch = register_model(cfg)
+    name = register_model(cfg, arch)
     scfg = SystemCfg(
         index=sv["index"], embedder=sv["embedder"], s_th_run=sv["s_th_run"],
         decode_slots=sv["decode_slots"],
         batched=BatchedRuntimeCfg(add_misses=sv["write_back"]),
-        engine=EngineCfg(arch=arch, smoke=False, max_len=sv["max_len"],
+        engine=EngineCfg(arch=name, smoke=False, max_len=sv["max_len"],
                          chunk=sv["chunk"], seed=weights_seed))
     return StorInfer.open(store, scfg, tokenizer=tok)

@@ -6,9 +6,11 @@ chip attached, and print what each needs of the chip's memory.
 
 For each configuration in ``BENCHMARK.json``: the engine's prefill at
 every prompt length its cells' traffic sends, the decode chunk at the
-configuration's slot count, the slot write, and the reference's weight
-init and forward as a run's check calls them, each on one chip of the
-described topology. A program the TPU compiler refuses fails here, and
+configuration's slot count and the slot write, over params at the
+configuration's stated dtype as the served engine holds them, and the
+reference's weight init and forward (the configuration's architecture
+module) as a run's check calls them, each on one chip of the described
+topology. A program the TPU compiler refuses fails here, and
 one whose arguments and temporaries pass the chip's HBM is reported.
 """
 from __future__ import annotations
@@ -28,7 +30,7 @@ def main(argv=None):
     ap.add_argument("--topology", default="v5e:2x2")
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from harness import costs, spec, system
+    from harness import spec, system
     root = HERE.parent
     system.program_on_path(root)
     import jax
@@ -79,15 +81,16 @@ def main(argv=None):
         lengths = sorted({r.prompt_len for w in bench["workloads"]
                           if w["config"] == entry["name"]
                           for r in plan(w, cfg) if r.kind == "miss"})
-        mcfg = get_config(system.register_model(cfg))
-        print(f"{entry['name']}: {costs.param_count(cfg) / 1e9:.3f} B "
+        arch = spec.load_arch(root, cfg)
+        mcfg = get_config(system.register_model(cfg, arch))
+        print(f"{entry['name']}: {arch.param_count(cfg) / 1e9:.3f} B "
               f"parameters, prompt lengths {lengths}, {sv['decode_slots']} "
               f"slots, max_len {sv['max_len']}", flush=True)
-        eng = Engine(mcfg, None, Tokenizer(["x"]),
-                     M.RunCfg(attn_impl="naive", remat=False),
-                     max_len=sv["max_len"], chunk=sv["chunk"])
+        eng = Engine(mcfg, None, Tokenizer(["x"]), max_len=sv["max_len"],
+                     chunk=sv["chunk"])
         params = place(jax.eval_shape(lambda: M.init_model(
-            jax.random.PRNGKey(0), mcfg, dtype=jnp.float32)))
+            jax.random.PRNGKey(0), mcfg,
+            dtype=jnp.dtype(cfg["torch_dtype"]))))
         B = sv["decode_slots"]
         cache = place(M.cache_struct(mcfg, B, sv["max_len"]))
         for n in lengths:
@@ -104,14 +107,14 @@ def main(argv=None):
         c = eng._write_slot.lower(cache, one_cache,
                                   sds((), jnp.int32)).compile()
         report("slot write", c)
-        items = R._frozen(cfg)
-        c = R._init.lower(items, sds((), jnp.int32)).compile()
+        c = jax.jit(lambda s: arch.init_weights(cfg, s)).lower(
+            sds((), jnp.int32)).compile()
         report("reference weights", c)
-        w = place(jax.eval_shape(lambda: R._init(items, 0)))
+        w = place(jax.eval_shape(lambda: arch.init_weights(cfg, 0)))
         pad = sv["max_len"]
         with jax.default_matmul_precision("highest"):
-            c = R._logits.lower(items, w, sds((8, pad), jnp.int32),
-                                False).compile()
+            c = R._logits.lower(arch.forward, R._frozen(cfg), w,
+                                sds((8, pad), jnp.int32), False).compile()
         report(f"reference forward (8, {pad})", c)
     print(f"largest serving program: {worst / 1e9:.3f} GB", flush=True)
 

@@ -203,11 +203,14 @@ def knowledge_view(cfg: dict):
 
 
 def open_cell(root: Path, cell: dict, seed: int, require_chip: bool = True):
-    """Set-up up to serving: the device, the compile cache, the store, the
-    system opened with weights from ``seed``, and the reference's own
-    stored pairs, vocabulary and answer lengths. ``store_s`` is the time
-    the store's one-off build took (0 when the checkout has it)."""
+    """Set-up up to serving: the configuration's architecture module
+    (``arch``, found before anything else opens), the device, the compile
+    cache, the store, the system opened with weights from ``seed`` once
+    ``arch`` has checked its widths, and the reference's own stored pairs,
+    vocabulary and answer lengths. ``store_s`` is the time the store's
+    one-off build took (0 when the checkout has it)."""
     from harness import system
+    arch = spec.load_arch(root, cell["cfg"])
     system.program_on_path(root)
     import jax
     device = (device_gate(cell["chips"]) if require_chip else
@@ -228,8 +231,9 @@ def open_cell(root: Path, cell: dict, seed: int, require_chip: bool = True):
     t = time.perf_counter()
     store = system.ensure_store(root, cfg, sut.pairs, sut.tok, log)
     sut.store_s = time.perf_counter() - t
-    sut.si = system.open_system(root, cfg, store, sut.tok, weights_seed)
-    sut.device, sut.weights_seed = device, weights_seed
+    sut.si = system.open_system(root, cfg, arch, store, sut.tok,
+                                weights_seed)
+    sut.device, sut.weights_seed, sut.arch = device, weights_seed, arch
     return sut
 
 
@@ -255,7 +259,7 @@ def run(argv=None, *, root: Path = None, require_chip: bool = True,
     cfg, mix = cell["cfg"], cell["mix"]
     seed = args.seed
     sut = open_cell(root, cell, seed, require_chip)
-    si, device = sut.si, sut.device
+    si, device, arch = sut.si, sut.device, sut.arch
 
     import jax
 
@@ -323,10 +327,9 @@ def run(argv=None, *, root: Path = None, require_chip: bool = True,
             log(f"failed request: {r.error}")
             break
     ctx = types.SimpleNamespace(
-        cfg=cfg, mix=mix, cell=cell, recs=recs, t0=t0, t_end=t_end,
-        window_s=args.seconds, deadline=deadline, snap0=snap0,
-        snap1=snap1, trace=None,
-        peaks=None, store_rows=si.store.count)
+        cfg=cfg, arch=arch, mix=mix, cell=cell, recs=recs, t0=t0,
+        t_end=t_end, window_s=args.seconds, deadline=deadline, snap0=snap0,
+        snap1=snap1, trace=None, peaks=None, store_rows=si.store.count)
     if args.trace:
         ctx.peaks = spec.load_peaks(root, device["kind"]) \
             if require_chip else None
@@ -356,7 +359,7 @@ def run(argv=None, *, root: Path = None, require_chip: bool = True,
     t = time.perf_counter()
     filler = system.filler_rows(cfg["store"], len(pairs))
     ref_store = C.RefStore(cfg["store"], pairs, filler)
-    checks = C.judge(cfg, mix, seed, recs, ref_store, vocab,
+    checks = C.judge(cfg, arch, mix, seed, recs, ref_store, vocab,
                      sut.weights_seed, control=control)
     log(f"reference check in {time.perf_counter() - t:.1f}s: "
         f"{checks['routes_checked']} routes, {checks['hits_checked']} hits, "

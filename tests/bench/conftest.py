@@ -59,7 +59,7 @@ def make_root(base: Path) -> Path:
     root = base / "checkout"
     (root / "bench").mkdir(parents=True)
     (root / "src").symlink_to(REPO / "src")
-    for sub in ("traffic", "metrics", "configs"):
+    for sub in ("traffic", "metrics", "configs", "arch"):
         shutil.copytree(BENCH / sub, root / "bench" / sub)
     shutil.copy(BENCH / "peaks.json", root / "bench" / "peaks.json")
     bench = json.loads((REPO / "BENCHMARK.json").read_text())

@@ -1,6 +1,6 @@
 """The benchmark's yardstick on its own: trace reduction, latency and
-rate arithmetic, operation and byte counts, cell selection and traffic
-generation. No program runs here."""
+rate arithmetic, operation and byte counts (Qwen3's from its architecture
+module), cell selection and traffic generation. No program runs here."""
 from __future__ import annotations
 
 import json
@@ -14,6 +14,7 @@ from harness import costs, spec, traffic, xtrace
 
 PEAKS = {"flops_per_s": {"bfloat16": 197e12, "int8": 393e12},
          "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
+QWEN3 = spec.load_arch(REPO, QWEN)
 
 
 # -- a small synthetic trace --------------------------------------------------
@@ -157,26 +158,43 @@ def test_hit_latency_readers_count_as_the_end_to_end_percentiles(q):
 def test_param_counts_match_published_sizes():
     # qwen3-1.7b: 1.72 B with the tied head (2.03 B were it untied, as
     # the model card's 2.0 B total counts the embedding twice)
-    assert costs.param_count(QWEN) == pytest.approx(1.7205e9, rel=1e-3)
+    assert QWEN3.param_count(QWEN) == pytest.approx(1.7205e9, rel=1e-3)
     untied = dict(QWEN, tie_word_embeddings=False)
-    assert costs.param_count(untied) - costs.param_count(QWEN) == \
+    assert QWEN3.param_count(untied) - QWEN3.param_count(QWEN) == \
         QWEN["vocab_size"] * QWEN["hidden_size"]
 
 
 def test_decode_step_bound_is_the_bf16_weights():
-    t, bound = costs.decode_step_least_s(QWEN, PEAKS, live=1.0,
+    t, bound = costs.decode_step_least_s(QWEN3, QWEN, PEAKS, live=1.0,
                                          kv_positions=40.0)
     assert bound == "memory"
-    w = costs.weight_bytes(QWEN)
+    w = QWEN3.weight_bytes(QWEN)
     assert w == pytest.approx(2 * 1.7205e9, rel=2e-3)
-    assert t == pytest.approx((w + 40 * costs.kv_bytes_per_position(QWEN))
+    assert t == pytest.approx((w + 40 * QWEN3.kv_bytes_per_position(QWEN))
                               / 819e9)
 
 
+def test_qwen3_counts_are_the_numbers_the_benchmark_has_read_by():
+    """The counts every Qwen3 roofline and MFU since the first benchmark
+    were read by, exactly: moving them into the architecture module
+    changed no arithmetic."""
+    assert QWEN3.param_count(QWEN) == 1_720_574_976
+    assert QWEN3.weight_bytes(QWEN) == 3_441_135_616
+    assert QWEN3.kv_bytes_per_position(QWEN) == 114_688
+    assert QWEN3.flops_per_token(QWEN, 0) == 3_441_131_520
+    assert QWEN3.flops_per_token(QWEN, 100) == 3_464_069_120
+    peaks = spec.load_peaks(REPO, "TPU v5 lite")
+    t, bound = costs.decode_step_least_s(QWEN3, QWEN, peaks, live=1.135,
+                                         kv_positions=45.4)
+    assert bound == "memory"
+    assert t == (3_441_135_616 + 114_688 * 45.4) / 819e9
+    assert round(t * 1e3, 5) == 4.20799
+
+
 def test_flops_per_token_counts_matmuls_and_attention():
-    s = costs.model_shapes(QWEN)
-    f0 = costs.flops_per_token(QWEN, 0)
-    f9 = costs.flops_per_token(QWEN, 9)
+    s = QWEN3.model_shapes(QWEN)
+    f0 = QWEN3.flops_per_token(QWEN, 0)
+    f9 = QWEN3.flops_per_token(QWEN, 9)
     assert f9 - f0 == 4 * s["layers"] * s["h"] * s["hd"] * 9
     # the gated MLP's three matmuls in each of 28 layers, and the head
     assert f0 > 2 * (28 * 3 * 2048 * 6144 + 151936 * 2048)
@@ -192,8 +210,8 @@ def test_scan_bound_is_the_store_bytes_below_hundreds_of_queries():
 
 
 def _ctx(**kw):
-    base = dict(cfg=QWEN, peaks=PEAKS, window_s=10.0, t0=0.0, t_end=10.0,
-                recs=[], trace=None, store_rows=150_016,
+    base = dict(cfg=QWEN, arch=QWEN3, peaks=PEAKS, window_s=10.0, t0=0.0,
+                t_end=10.0, recs=[], trace=None, store_rows=150_016,
                 snap0={"stages": {"search": {"items": 0, "mean_wait_ms": 0},
                                   "resolve": {"items": 0,
                                               "mean_wait_ms": 0}},
@@ -220,7 +238,7 @@ def test_readers_from_counters_and_shapes():
     read = lambda name: spec.load_reader(REPO, name).read(ctx)  # noqa: E731
     assert read("search_wait_ms") == pytest.approx(3.0)
     assert read("decode_wave_size.novel") == pytest.approx(1.5)
-    flops = sum(costs.flops_per_token(QWEN, 8 + i) for i in range(40))
+    flops = sum(QWEN3.flops_per_token(QWEN, 8 + i) for i in range(40))
     scan = 2 * 150_016 * 384 * 10
     want = 100 * (flops / 197e12 + scan / 393e12) / 10
     assert read("mfu.novel") == pytest.approx(want)
@@ -233,7 +251,7 @@ def test_readers_from_counters_and_shapes():
 def test_roofline_readers_stay_under_100_at_the_least_time():
     t = small_trace()
     # a decode step as fast as the bound, and a scan as fast as its bound
-    least_step, _ = costs.decode_step_least_s(QWEN, PEAKS, 1.0, 8.0)
+    least_step, _ = costs.decode_step_least_s(QWEN3, QWEN, PEAKS, 1.0, 8.0)
     t.devices[0].modules = [("jit__decode_chunk_impl", 0,
                              int(round(least_step * 8 * 1e9)))]
     least_scan, _ = costs.scan_least_s(150_016, 384, 2.0, PEAKS)
@@ -259,6 +277,20 @@ def test_each_cell_reports_setup_another_end_to_end_and_a_layer():
             spec.load_reader(REPO, m["name"])
         for m in e2e:
             spec.e2e_kind(m)
+
+
+def test_every_configuration_names_an_architecture_module():
+    bench = spec.load_benchmark(REPO)
+    for entry in bench["configs"]:
+        cfg = json.loads((REPO / entry["file"]).read_text())
+        assert spec.load_arch(REPO, cfg).param_count(cfg) > 0
+
+
+def test_an_unknown_architecture_is_refused_with_the_missing_path():
+    with pytest.raises(spec.SpecError, match="bench/arch/nope.py not found"):
+        spec.load_arch(REPO, dict(QWEN, model_type="nope"))
+    with pytest.raises(spec.SpecError, match="not found"):
+        spec.load_arch(REPO, dict(QWEN, model_type="../harness/costs"))
 
 
 def test_unknown_device_kind_is_an_error():
