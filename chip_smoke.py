@@ -343,8 +343,8 @@ def lm_phase(engine, prompt, n_steps=4):
     for s in range(n_steps):
         nxt = int(np.argmax(got[-1]))
         seq.append(nxt)
-        logits, cache = step(e.params, jnp.asarray([[nxt]], jnp.int32),
-                             cache, jnp.asarray(len(ids) + s, jnp.int32))
+        logits, cache, _ = step(e.params, jnp.asarray([[nxt]], jnp.int32),
+                                cache, jnp.asarray(len(ids) + s, jnp.int32))
         got.append(np.asarray(logits[0, -1], np.float32))
     f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), e.params)
     with jax.default_matmul_precision("highest"):

@@ -48,8 +48,15 @@ class ModelConfig:
     mlp_act: str = "silu"
     gated_mlp: bool = True  # SwiGLU-style
     rope_theta: float = 1e4
-    rope_kind: str = "standard"  # standard | mrope | none
+    rope_kind: str = "standard"  # standard | mrope | yarn | none
     mrope_sections: Tuple[int, ...] = ()
+    # --- yarn (rope_kind "yarn"; DeepSeek-V2's rope_scaling) ----------------
+    rope_factor: float = 1.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     tie_embeddings: bool = False
     # --- MoE ----------------------------------------------------------------
     n_experts: int = 0
@@ -59,6 +66,12 @@ class ModelConfig:
     first_dense_layers: int = 0
     d_ff_dense: int = 0  # width of leading dense layers in MoE stacks
     moe_capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+    # the share of the routed experts this chip holds: experts
+    # [expert_offset, expert_offset + n_experts_held) of n_experts (0: all)
+    n_experts_held: int = 0
+    expert_offset: int = 0
     # --- MLA (deepseek) -----------------------------------------------------
     use_mla: bool = False
     kv_lora_rank: int = 0
@@ -104,6 +117,10 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def d_inner_ssm(self) -> int:
@@ -154,7 +171,7 @@ class ModelConfig:
         elif self.family == "moe":
             n_moe = self.n_layers - self.first_dense_layers
             per_moe = attn_params()
-            per_moe += self.n_experts * 3 * d * self.d_ff_expert
+            per_moe += self.experts_held * 3 * d * self.d_ff_expert
             per_moe += self.n_shared_experts * 3 * d * self.d_ff_expert
             per_moe += d * self.n_experts  # router
             total += n_moe * per_moe

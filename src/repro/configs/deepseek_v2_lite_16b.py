@@ -1,9 +1,16 @@
-"""DeepSeek-V2-Lite (15.7B total / 2.4B active) [arXiv:2405.04434; hf].
+"""DeepSeek-V2-Lite (15.7B total / 2.4B active) [arXiv:2405.04434].
 
-MLA attention (kv_lora_rank=512, decoupled RoPE 64), 64 routed experts top-6 +
-2 shared experts, first layer dense (d_ff 10944). The assignment line lists both
-"64e top-6" and "2 shared+160 routed"; we follow the HF V2-Lite checkpoint
-config (64 routed + 2 shared, top-6) — see DESIGN.md §4.
+The published config (https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/
+blob/main/config.json): MLA attention (kv_lora_rank 512, no q compression,
+decoupled rope head 64), one leading dense layer (intermediate 10944), then
+26 MoE layers of 64 routed experts (1408 wide, softmax gate, greedy top-6)
+and 2 shared experts. ``norm_topk_prob`` false: the top-6 weights are the
+softmax probabilities as they are, times ``routed_scaling_factor`` 1.
+``rope_scaling``: yarn, factor 40, beta_fast 32, beta_slow 1, mscale and
+mscale_all_dim 0.707, original_max_position_embeddings 4096.
+
+Registered whole (every chip holds all 64 experts); a deployment that
+spreads the experts over chips sets ``n_experts_held``/``expert_offset``.
 """
 from repro.configs.base import ModelConfig, register
 
@@ -31,6 +38,16 @@ CONFIG = register(ModelConfig(
     d_ff_expert=1408,
     first_dense_layers=1,
     d_ff_dense=10944,
+    norm_topk_prob=False,
+    routed_scaling_factor=1.0,
+    # rope: yarn
     rope_theta=1e4,
+    rope_kind="yarn",
+    rope_factor=40.0,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_original_max_pos=4096,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
     norm_eps=1e-6,
 ))

@@ -193,9 +193,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def blockwise_gqa(q, k, v, *, causal=True, mask_offset=0, q_block=512,
-                  kv_block=1024, schedule="rect", constrain=None):
+                  kv_block=1024, schedule="rect", constrain=None, scale=None):
     """q: (B,S,Hq,D) k,v: (B,T,Hkv,D[v]) -> (B,S,Hq,Dv).
 
+    scale: the softmax scale (default D^-0.5).
     mask_offset: queries at global position ``mask_offset + i`` may attend
     keys at positions j <= mask_offset + i (must be a python int).
     constrain: optional fn(tensor, batch_dim) -> tensor applying a batch
@@ -225,7 +226,8 @@ def blockwise_gqa(q, k, v, *, causal=True, mask_offset=0, q_block=512,
     kg = constrain(jnp.moveaxis(k.reshape(B, nk, kb, Hkv, D), 1, 0), 1, 3)
     vg = constrain(jnp.moveaxis(v.reshape(B, nk, kb, Hkv, Dv), 1, 0), 1, 3)
 
-    c = _Cfg(causal=causal, scale=D ** -0.5, mask_offset=int(mask_offset),
+    scale = D ** -0.5 if scale is None else scale
+    c = _Cfg(causal=causal, scale=scale, mask_offset=int(mask_offset),
              T=T, qb=qb, kb=kb, constrain=constrain)
     if schedule == "tri" and causal:
         out = _tri_fwd(c, qg, kg, vg)

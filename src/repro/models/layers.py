@@ -6,6 +6,7 @@ Shapes use B=batch, S=query length, T=key length, H=heads, K=kv heads, D=head di
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -137,13 +138,46 @@ def rope_freqs(head_dim, theta):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta):
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def yarn_mscale(scale, mscale):
+    """Yarn's magnitude factor 0.1 * mscale * ln(scale) + 1 (1 when the
+    context is not stretched)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim, theta, factor, beta_fast, beta_slow,
+               original_max_pos):
+    """Yarn's inverse frequencies (DeepSeek-V2's rope_scaling "yarn"):
+    theta's own below the correction range, theta's divided by ``factor``
+    above it, blended by a linear ramp over the range. The range's ends are
+    the dims whose wavelength turns ``beta_fast`` and ``beta_slow`` times
+    over ``original_max_pos`` positions."""
+    def dim_at(rotations):
+        return (head_dim * math.log(original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_at(beta_fast)), 0)
+    high = min(math.ceil(dim_at(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extrapolated = rope_freqs(head_dim, theta)
+    return (extrapolated / factor * ramp
+            + extrapolated * (1 - ramp)).astype(np.float32)
+
+
+def apply_rope(x, positions, theta, inv_freq=None, scale=1.0):
+    """x: (B, S, H, D); positions: (B, S) int32. ``inv_freq`` (D/2,) in
+    place of theta's, and cos and sin times ``scale``, for scaled ropes."""
     D = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(D, theta))             # (D/2,)
+    inv = jnp.asarray(rope_freqs(D, theta) if inv_freq is None
+                      else inv_freq)                     # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * inv  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -6,6 +6,8 @@ into the query and attend directly over the compressed cache (c_kv ‖
 k_rope) — per-token cost O(T·(r + d_rope)·H) instead of
 O(T·(d_nope+d_rope)·H + T·r·H·d), the trick that makes MLA serve-efficient).
 The cache stores only (c_kv: r, k_rope: d_rope) per token (576 for V2-Lite).
+Under yarn (``rope_kind`` "yarn", V2-Lite's rope_scaling) the rope heads take
+yarn's frequencies and the softmax its temperature (``softmax_scale``).
 """
 from __future__ import annotations
 
@@ -13,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import (dense, dense_init, rmsnorm, rmsnorm_init,
-                                 apply_rope)
+                                 apply_rope, yarn_freqs, yarn_mscale)
 
 
 def mla_init(key, cfg, dtype=None):
@@ -36,6 +38,27 @@ def mla_init(key, cfg, dtype=None):
     return p
 
 
+def rope(cfg, x, positions):
+    """The rope of the query and key rope heads, x (B, S, H, d_rope)."""
+    if cfg.rope_kind != "yarn":
+        return apply_rope(x, positions, cfg.rope_theta)
+    inv = yarn_freqs(x.shape[-1], cfg.rope_theta, cfg.rope_factor,
+                     cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+                     cfg.yarn_original_max_pos)
+    scale = (yarn_mscale(cfg.rope_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim))
+    return apply_rope(x, positions, cfg.rope_theta, inv_freq=inv, scale=scale)
+
+
+def softmax_scale(cfg):
+    """(d_nope + d_rope)^-0.5, times yarn's mscale(factor, mscale_all_dim)^2
+    under yarn."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_kind == "yarn" and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _project_q(cfg, p, x):
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -50,5 +73,5 @@ def _project_ckv(cfg, p, x, positions):
     dkv = dense(p["wdkv"], x)
     ckv = rmsnorm(p["ckv_norm"], dkv[..., :r], cfg.norm_eps)
     krope = dkv[..., None, r:]  # single shared rope head
-    krope = apply_rope(krope, positions, cfg.rope_theta)
+    krope = rope(cfg, krope, positions)
     return ckv, krope

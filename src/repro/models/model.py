@@ -49,7 +49,7 @@ class RunCfg:
     schedule: str = "rect"            # rect | tri  (causal block skipping)
     q_block: int = 512
     kv_block: int = 1024
-    moe_impl: str = "scatter"         # scatter | einsum | ep
+    moe_impl: str = "scatter"         # scatter | einsum | ep | dropless
     moe_group: int = 2048
     remat: bool = True
     scan_layers: bool = True
@@ -133,7 +133,15 @@ def init_block(key, cfg, kind, dtype):
 
 def _stack_init(key, cfg, kind, n, dtype):
     keys = jax.random.split(key, n)
-    return jax.vmap(lambda k: init_block(k, cfg, kind, dtype))(keys)
+
+    def init(k):
+        return init_block(k, cfg, kind, dtype)
+
+    if kind == "moe" and cfg.experts_held != cfg.n_experts:
+        # each layer draws all its experts and keeps its share: one layer
+        # at a time, so no more than one layer's draw is ever resident
+        return jax.lax.map(init, keys)
+    return jax.vmap(init)(keys)
 
 
 def main_block_kind(cfg) -> str:
@@ -253,7 +261,7 @@ def mla_fullseq(cfg, run, p, x, positions, *, mask_offset=0):
     H = cfg.n_heads
     nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     qn, qr = Mla._project_q(cfg, p, x)
-    qr = Lyr.apply_rope(qr, positions, cfg.rope_theta)
+    qr = Mla.rope(cfg, qr, positions)
     ckv, krope = Mla._project_ckv(cfg, p, x, positions)
     kn = Lyr.weight_einsum("bsr,rhn->bshn", ckv, p["wuk"])
     v = Lyr.weight_einsum("bsr,rhv->bshv", ckv, p["wuv"])
@@ -262,11 +270,13 @@ def mla_fullseq(cfg, run, p, x, positions, *, mask_offset=0):
     k = jnp.concatenate([kn, kr], axis=-1)
     if run.attn_impl == "naive":
         mask = Lyr.causal_mask(S, S, mask_offset)
-        out = Lyr.gqa_scores_softmax_out(q, k, v, mask, (nope + rd) ** -0.5)
+        out = Lyr.gqa_scores_softmax_out(q, k, v, mask,
+                                         Mla.softmax_scale(cfg))
     else:
         out = blockwise_gqa(q, k, v, causal=True, mask_offset=mask_offset,
                             q_block=run.q_block, kv_block=run.kv_block,
-                            schedule=run.schedule, constrain=_batch_cb(run))
+                            schedule=run.schedule, constrain=_batch_cb(run),
+                            scale=Mla.softmax_scale(cfg))
     return Lyr.dense(p["wo"], out.reshape(B, S, H * vd)), \
         {"ckv": ckv, "krope": krope[:, :, 0, :]}
 
@@ -327,14 +337,13 @@ def gqa_decode(cfg, run, p, x, kc, vc, cache_len, *, mrope_positions=None):
 def mla_decode(cfg, run, p, x, ckv_c, krope_c, cache_len):
     """Absorbed decode over the compressed cache (B,M,r)/(B,M,dr)."""
     B = x.shape[0]
-    H = cfg.n_heads
-    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    H, vd = cfg.n_heads, cfg.v_head_dim
     positions = jnp.full((B, 1), cache_len, jnp.int32)
     qn, qr = Mla._project_q(cfg, p, x)
-    qr = Lyr.apply_rope(qr, positions, cfg.rope_theta)
+    qr = Mla.rope(cfg, qr, positions)
     ckv_new, krope_new = Mla._project_ckv(cfg, p, x, positions)
     q_c = Lyr.weight_einsum("bshn,rhn->bshr", qn, p["wuk"])
-    scale = (nope + rd) ** -0.5
+    scale = Mla.softmax_scale(cfg)
 
     if run.decode_attn == "seq_sharded" and run.mesh is not None:
         from repro.distributed.decode_attn import mla_decode_seq_sharded
@@ -363,13 +372,21 @@ def mla_decode(cfg, run, p, x, ckv_c, krope_c, cache_len):
 
 
 def apply_moe(cfg, run, p, x):
+    """x (B, S, d) -> (y, aux_loss, routes): ``routes`` (B*S, K), the
+    experts each token routed to, from the dropless serving layer, else
+    None."""
+    if run.moe_impl == "dropless":
+        y, routes = Moe.moe_ffn_dropless(cfg, p, x)
+        return y, jnp.zeros((), jnp.float32), routes
     if run.moe_impl == "einsum":
-        return Moe.moe_ffn_einsum(cfg, p, x, run.moe_group)
-    if run.moe_impl == "ep":
+        y, aux = Moe.moe_ffn_einsum(cfg, p, x, run.moe_group)
+    elif run.moe_impl == "ep":
         from repro.distributed.moe_parallel import moe_ffn_ep
-        return moe_ffn_ep(cfg, p, x, mesh=run.mesh, ep_axis=run.ep_axis,
-                          batch_axes=run.batch_axes)
-    return Moe.moe_ffn(cfg, p, x)
+        y, aux = moe_ffn_ep(cfg, p, x, mesh=run.mesh, ep_axis=run.ep_axis,
+                            batch_axes=run.batch_axes)
+    else:
+        y, aux = Moe.moe_ffn(cfg, p, x)
+    return y, aux, None
 
 
 # ---------------------------------------------------------------------------
@@ -409,8 +426,8 @@ def block_fullseq(cfg, run, p, x, positions, *, kind, mrope_positions=None,
             kv = dict(kv)
             kv["xk"], kv["xv"] = xk, xv  # static cross K/V for the cache
         if kind == "moe":
-            h2, aux = apply_moe(cfg, run, p["moe"],
-                                Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
+            h2, aux, _ = apply_moe(cfg, run, p["moe"],
+                                   Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
         else:
             h2 = Lyr.mlp(cfg, p["mlp"], Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
         return x + h2, aux, kv
@@ -426,8 +443,10 @@ def block_fullseq(cfg, run, p, x, positions, *, kind, mrope_positions=None,
 
 def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
                  mrope_positions=None):
-    """One layer, one token. cache_sl = this layer's cache slice (no L dim)."""
-    aux = jnp.zeros((), jnp.float32)
+    """One layer, one token. cache_sl = this layer's cache slice (no L dim).
+    Returns (x, new_cache, routes), ``routes`` as ``apply_moe`` gives
+    them (None but for an expert layer)."""
+    routes = None
     if kind in ("dense", "moe", "moe_dense0", "dec"):
         xin = Lyr.rmsnorm(p["ln1"], x, cfg.norm_eps)
         if cfg.use_mla:
@@ -451,17 +470,17 @@ def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
             x = x + Lyr.dense(p["xattn"]["wo"],
                               out.reshape(B, 1, cfg.n_heads * hd))
             new_cache["xk"], new_cache["xv"] = cache_sl["xk"], cache_sl["xv"]
+        xn = Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps)
         if kind == "moe":
-            h2, aux = apply_moe(cfg, run, p["moe"],
-                                Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
+            h2, _, routes = apply_moe(cfg, run, p["moe"], xn)
         else:
-            h2 = Lyr.mlp(cfg, p["mlp"], Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
-        return x + h2, new_cache
+            h2 = Lyr.mlp(cfg, p["mlp"], xn)
+        return x + h2, new_cache, routes
     if kind == "ssm":
         h, (hs, conv) = Ssm.ssm_decode(cfg, p["ssm"],
                                        Lyr.rmsnorm(p["ln"], x, cfg.norm_eps),
                                        cache_sl["h"], cache_sl["conv"])
-        return x + h, {"h": hs, "conv": conv}
+        return x + h, {"h": hs, "conv": conv}, routes
     raise ValueError(kind)
 
 
@@ -780,8 +799,11 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
                 mrope_positions=None):
     """token (B,1) int32; cache per ``cache_struct``; cache_len () int32.
 
-    Returns (logits (B,1,V), new_cache).
+    Returns (logits (B,1,V), new_cache, routes): ``routes`` (L_moe, B, K),
+    the experts each token routed to in each scanned expert layer, where
+    the expert layer reports them (``apply_moe``), else None.
     """
+    routes = None
     x = embed_tokens(cfg, params, token, run.stream_dtype)
     kind = main_block_kind(cfg)
 
@@ -793,7 +815,8 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
         for i in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
             csl = jax.tree_util.tree_map(lambda a: a[i], cache)
-            x, nc = block_decode(cfg, run, lp, x, csl, cache_len, kind="dec")
+            x, nc, _ = block_decode(cfg, run, lp, x, csl, cache_len,
+                                    kind="dec")
             new_layers.append(nc)
         new_cache = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
                                            *new_layers)
@@ -816,7 +839,8 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
                 inv += 1
             lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
             csl = {"h": cache["h"][i], "conv": cache["conv"][i]}
-            x, nc = block_decode(cfg, run, lp, x, csl, cache_len, kind="ssm")
+            x, nc, _ = block_decode(cfg, run, lp, x, csl, cache_len,
+                                    kind="ssm")
             hs.append(nc["h"])
             convs.append(nc["conv"])
         new_cache = {"h": jnp.stack(hs), "conv": jnp.stack(convs),
@@ -832,9 +856,9 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
             for i in range(n_dense0):
                 lp = jax.tree_util.tree_map(lambda a: a[i], params["dense0"])
                 csl = jax.tree_util.tree_map(lambda a: a[i], c0)
-                x, nc = block_decode(cfg, run, lp, x, csl, cache_len,
-                                     kind="moe_dense0",
-                                     mrope_positions=mrope_positions)
+                x, nc, _ = block_decode(cfg, run, lp, x, csl, cache_len,
+                                        kind="moe_dense0",
+                                        mrope_positions=mrope_positions)
                 new_cache_parts.append(
                     jax.tree_util.tree_map(lambda a: a[None], nc))
             cache_main = jax.tree_util.tree_map(lambda a: a[n_dense0:], cache)
@@ -843,34 +867,37 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
 
         def scan_body(x, inp):
             lp, csl = inp
-            x, nc = block_decode(cfg, run, lp, x, csl, cache_len, kind=kind,
-                                 mrope_positions=mrope_positions)
-            return x, nc
+            x, nc, r = block_decode(cfg, run, lp, x, csl, cache_len,
+                                    kind=kind,
+                                    mrope_positions=mrope_positions)
+            return x, (nc, r)
 
         if run.scan_layers:
-            x, nc_main = jax.lax.scan(scan_body, x,
-                                      (params["blocks"], cache_main))
+            x, (nc_main, routes) = jax.lax.scan(
+                scan_body, x, (params["blocks"], cache_main))
         else:
-            ncl = []
+            outs = []
             n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
             for i in range(n):
                 lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
                 csl = jax.tree_util.tree_map(lambda a: a[i], cache_main)
-                x, nc = scan_body(x, (lp, csl))
-                ncl.append(nc)
-            nc_main = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ncl)
+                x, out = scan_body(x, (lp, csl))
+                outs.append(out)
+            nc_main, routes = jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), *outs)
         new_cache_parts.append(nc_main)
         new_cache = jax.tree_util.tree_map(
             lambda *a: jnp.concatenate(a, axis=0), *new_cache_parts) \
             if len(new_cache_parts) > 1 else new_cache_parts[0]
 
-    return lm_logits(cfg, run, params, x), new_cache
+    return lm_logits(cfg, run, params, x), new_cache, routes
 
 
 def serve_step(cfg, params, token, cache, cache_len, rng, run=RunCfg(),
                temperature=0.0):
     """decode_step + sampling -> (next_token (B,1), new_cache)."""
-    logits, new_cache = decode_step(cfg, params, token, cache, cache_len, run)
+    logits, new_cache, _ = decode_step(cfg, params, token, cache, cache_len,
+                                       run)
     lg = logits[:, -1, :].astype(jnp.float32)
     if temperature and temperature > 0:
         nxt = jax.random.categorical(rng, lg / temperature, axis=-1)

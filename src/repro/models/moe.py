@@ -3,8 +3,13 @@
 The local path (this file) computes exact top-k routing with a per-call token
 capacity: tokens are scattered into an (E, C, d) buffer by (expert, rank)
 slot, experts run as one batched matmul, and results are gathered back and
-combined with renormalized router weights. Overflowing tokens are dropped
-(standard capacity-factor semantics) — the residual stream carries them.
+combined with the router weights (renormalized over the top k unless
+``cfg.norm_topk_prob`` is off). Overflowing tokens are dropped (standard
+capacity-factor semantics) — the residual stream carries them.
+
+``moe_ffn_dropless`` is the serving layer: it drops nothing, and holds only
+the chip's share of the routed experts (``cfg.n_experts_held`` from
+``cfg.expert_offset``) while routing over all ``cfg.n_experts``.
 
 Distributed variants (expert-parallel all-to-all via shard_map) live in
 ``repro.distributed.moe_parallel``; they reuse these param layouts.
@@ -21,9 +26,13 @@ def moe_init(key, cfg, dtype=None):
     d, ffe, E = cfg.d_model, cfg.d_ff_expert, cfg.n_experts
     dtype = dtype or jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 5)
+    lo, n = cfg.expert_offset, cfg.experts_held
 
     def ew(k, a, b):
-        return (jax.random.normal(k, (E, a, b), jnp.float32) * (a ** -0.5)).astype(dtype)
+        # a share is sliced from the whole layer's draw, so it holds the very
+        # experts the whole layer has at its positions
+        w = jax.random.normal(k, (E, a, b), jnp.float32)[lo:lo + n]
+        return (w * (a ** -0.5)).astype(dtype)
 
     p = {
         "router": dense_init(ks[0], d, E, jnp.float32),  # router kept in f32
@@ -47,9 +56,16 @@ def route(cfg, p, x2d):
     logits = jax.lax.dot_general(
         x2d, w_r, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    return _top_k(cfg, logits)
+
+
+def _top_k(cfg, logits):
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, cfg.experts_per_tok)
-    w = w / (w.sum(-1, keepdims=True) + 1e-9)
+    if cfg.norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-9)
+    if cfg.routed_scaling_factor != 1.0:
+        w = w * cfg.routed_scaling_factor
     return w, idx, probs
 
 
@@ -90,8 +106,16 @@ def expert_ffn(cfg, experts, buf):
     return weight_einsum("ecf,efd->ecd", h1 * h3, experts["w2"])
 
 
+def _whole(cfg):
+    if cfg.experts_held != cfg.n_experts:
+        raise ValueError(f"{cfg.name}: holds {cfg.experts_held} of "
+                         f"{cfg.n_experts} experts; only the dropless layer "
+                         "computes a share")
+
+
 def moe_ffn(cfg, p, x):
     """x: (B, S, d) -> (y, aux_loss). Exact top-k with capacity drop."""
+    _whole(cfg)
     B, S, d = x.shape
     T = B * S
     x2d = x.reshape(T, d)
@@ -110,6 +134,34 @@ def moe_ffn(cfg, p, x):
     if cfg.n_shared_experts:
         y = y + mlp(cfg, p["shared"], x2d)
     return y.reshape(B, S, d), load_balance_loss(cfg, probs, idx)
+
+
+def moe_ffn_dropless(cfg, p, x):
+    """x: (B, S, d) -> (y, routes). The serving layer: the router's
+    ``n_experts`` outputs in f32 at full precision (as the published gate
+    computes them), its top-k weights, and then each held expert over every
+    token, weighted by its gate, which is zero for a token that did not
+    route to it. No token is dropped, and no token's output depends on the
+    other tokens of the batch. Only the held experts' part of the routed
+    result is computed, plus the shared experts. ``routes`` (T, K) are the
+    global expert ids each token routed to."""
+    B, S, d = x.shape
+    T = B * S
+    x2d = x.reshape(T, d)
+    logits = jnp.matmul(x2d.astype(jnp.float32), p["router"]["w"],
+                        precision=jax.lax.Precision.HIGHEST)
+    w, idx, _ = _top_k(cfg, logits)
+    held = cfg.expert_offset + jnp.arange(cfg.experts_held)
+    gate = jnp.sum(jnp.where(idx[:, :, None] == held, w[:, :, None], 0.0),
+                   axis=1)                                        # (T, n)
+    ex = p["experts"]
+    h = jax.nn.silu(weight_einsum("td,edf->etf", x2d, ex["w1"])) * \
+        weight_einsum("td,edf->etf", x2d, ex["w3"])
+    y = weight_einsum("etf,efd->etd", h, ex["w2"])                # (n, T, d)
+    y = jnp.einsum("etd,te->td", y, gate.astype(y.dtype))
+    if cfg.n_shared_experts:
+        y = y + mlp(cfg, p["shared"], x2d)
+    return y.reshape(B, S, d).astype(x.dtype), idx
 
 
 # ---------------------------------------------------------------------------
@@ -142,6 +194,7 @@ def moe_ffn_einsum(cfg, p, x, group_size=2048):
     (grok: d_ff 32768) so dispatch FLOPs amortize; thin-expert models
     (deepseek) use the shard_map EP path in repro.distributed.moe_parallel.
     """
+    _whole(cfg)
     B, S, d = x.shape
     T = B * S
     g = min(group_size, T)

@@ -44,9 +44,18 @@ def sample_token(logits, rng, temperature):
 
 # The engine's run: the residual stream, norms, softmax and logits in f32
 # over params held at the configuration's dtype; each matmul takes a bf16
-# weight as it is, with its activation in bf16, accumulating in f32.
+# weight as it is, with its activation in bf16, accumulating in f32. Expert
+# layers drop no token, so a live slot's answer never depends on the others.
 SERVE_RUN = M.RunCfg(attn_impl="naive", remat=False,
-                     stream_dtype=jnp.float32)
+                     stream_dtype=jnp.float32, moe_impl="dropless")
+
+
+def _experts_touched(cfg, routes, live):
+    """Held experts that at least one live slot routed to, summed over the
+    expert layers: routes (L_moe, B, K) global expert ids, live (B,)."""
+    held = cfg.expert_offset + jnp.arange(cfg.experts_held)
+    hit = (routes[..., None] == held) & live[None, :, None, None]
+    return jnp.sum(jnp.any(hit, axis=(1, 2)).astype(jnp.int32))
 
 
 class Engine:
@@ -75,13 +84,19 @@ class Engine:
     def _decode_chunk_impl(self, params, token, cache, cache_len, rng,
                            temperature, live):
         """Runs ``chunk`` decode steps. live: (B,) bool — dead slots decode
-        but their cache writes are masked out (slot freed semantics)."""
+        but their cache writes are masked out (slot freed semantics).
+        Returns (token, cache, cache_len, tokens (B, chunk)), and where the
+        expert layers report their routes (the dropless serving layer) a
+        fifth output, int32 (3,), summed over expert layers and the steps
+        with a live slot: the held experts that at least one live slot
+        routed to, the layer steps, and the held experts those layer steps
+        read (all of them: the dropless layer reads every held expert)."""
 
         def body(carry, _):
             tok, cache, clen, rng = carry
             rng, sub = jax.random.split(rng)
-            logits, new_cache = M.decode_step(self.cfg, params, tok, cache,
-                                              clen, self.run)
+            logits, new_cache, routes = M.decode_step(self.cfg, params, tok,
+                                                      cache, clen, self.run)
             nxt = sample_token(logits[:, -1, :], sub, temperature)[:, None]
             nxt = nxt.astype(jnp.int32)
             keep = live[:, None]
@@ -90,11 +105,19 @@ class Engine:
                 lambda n, o: jnp.where(
                     jnp.reshape(live, (1, -1) + (1,) * (n.ndim - 2)), n, o),
                 new_cache, cache)
-            return (nxt, new_cache, clen + 1, rng), nxt[:, 0]
+            touched = None if routes is None else \
+                _experts_touched(self.cfg, routes, live)
+            return (nxt, new_cache, clen + 1, rng), (nxt[:, 0], touched)
 
-        (tok, cache, clen, _), toks = jax.lax.scan(
+        (tok, cache, clen, _), (toks, touched) = jax.lax.scan(
             body, (token, cache, cache_len, rng), None, length=self.chunk)
-        return tok, cache, clen, jnp.transpose(toks)  # (B, chunk)
+        out = (tok, cache, clen, jnp.transpose(toks))  # toks (B, chunk)
+        if touched is None:
+            return out
+        n_moe = self.cfg.n_layers - self.cfg.first_dense_layers
+        layer_steps = n_moe * self.chunk * jnp.any(live).astype(jnp.int32)
+        return out + (jnp.stack([jnp.sum(touched), layer_steps,
+                                 layer_steps * self.cfg.experts_held]),)
 
     def _write_slot_impl(self, batch_cache, one_cache, slot):
         """Insert a prefilled single-request cache at batch slot ``slot``."""
@@ -173,7 +196,7 @@ class Session:
         t0 = time.perf_counter()
         self.rng, sub = jax.random.split(self.rng)
         live = jnp.ones((1,), bool)
-        self.token, self.cache, self.cache_len, toks = \
+        self.token, self.cache, self.cache_len, toks, *_ = \
             self.e._decode_chunk(self.e.params, self.token, self.cache,
                                  self.cache_len + 1, sub,
                                  self.temperature, live)
@@ -230,7 +253,11 @@ class BatchScheduler:
     continuously; ``waves`` / ``admitted`` / ``slot_uses`` account for
     the reuse, ``slot_wait_s`` (submit to admission, summed over admitted
     requests) for the wait, and ``len_cuts`` for the waves closed by a
-    prompt-length mismatch while a slot was still free."""
+    prompt-length mismatch while a slot was still free. A model with expert
+    layers counts ``experts_touched`` (held experts that a live slot routed
+    to, summed over expert layers and decode steps), ``moe_layer_steps``
+    (expert layers times decode steps with a live slot) and
+    ``experts_read`` (the held experts those layer steps read)."""
 
     def __init__(self, engine: Engine, batch_size: int = 4):
         self.e = engine
@@ -249,6 +276,10 @@ class BatchScheduler:
         self.slot_uses = [0] * batch_size   # admissions per slot (reuse)
         self.slot_wait_s = 0.0              # submit -> admission, summed
         self.len_cuts = 0                   # waves closed on prompt length
+        self.experts_touched = 0            # held experts live slots routed
+        #                                     to, over expert layers x steps
+        self.moe_layer_steps = 0            # expert layers x live steps
+        self.experts_read = 0               # held experts those steps read
 
     def submit(self, req: Request):
         req.t_submit = time.perf_counter()
@@ -346,12 +377,17 @@ class BatchScheduler:
             self.rng, sub = jax.random.split(self.rng)
             temps = [r.temperature for r in self.reqs if r is not None]
             temp = temps[0] if temps and temps[0] is not None else None
-            self.token, self.cache, self.cache_len, toks = \
+            self.token, self.cache, self.cache_len, toks, *experts = \
                 self.e._decode_chunk(self.e.params, self.token, self.cache,
                                      self.cache_len + 1, sub, temp,
                                      jnp.asarray(self.live))
             self.cache_len = self.cache_len - 1
             toks = np.asarray(toks)
+            if experts:
+                touched, layer_steps, read = np.asarray(experts[0]).tolist()
+                self.experts_touched += touched
+                self.moe_layer_steps += layer_steps
+                self.experts_read += read
             for slot in range(self.B):
                 r = self.reqs[slot]
                 if r is None:

@@ -431,6 +431,9 @@ class ServingPipeline:
                                     "admitted": sched.admitted,
                                     "slot_wait_s": sched.slot_wait_s,
                                     "len_cuts": sched.len_cuts,
+                                    "experts_touched": sched.experts_touched,
+                                    "moe_layer_steps": sched.moe_layer_steps,
+                                    "experts_read": sched.experts_read,
                                     "slot_uses": list(sched.slot_uses)}
         return snap
 

@@ -77,7 +77,7 @@ def test_prefill_decode_matches_forward(arch_setup):
         np.asarray(pre_logits[:, 0]), np.asarray(full_logits[:, S - 2]),
         rtol=2e-2, atol=2e-2)
 
-    logits, new_cache = M.decode_step(
+    logits, new_cache, _ = M.decode_step(
         cfg, params, batch["tokens"][:, S - 1:S], cache,
         jnp.asarray(S - 1, jnp.int32), RUN)
     np.testing.assert_allclose(

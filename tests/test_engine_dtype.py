@@ -191,8 +191,8 @@ def test_bf16_engine_logits_match_forward(engine):
     cache = e._write_slot(M.init_cache(e.cfg, 1, e.max_len), one,
                           jnp.asarray(0, jnp.int32))
     nxt = int(jnp.argmax(logits[0, -1]))
-    step, _ = jax.jit(lambda p, t, c, n: M.decode_step(e.cfg, p, t, c, n,
-                                                        e.run))(
+    step, _, _ = jax.jit(lambda p, t, c, n: M.decode_step(
+        e.cfg, p, t, c, n, e.run))(
         e.params, jnp.asarray([[nxt]], jnp.int32), cache,
         jnp.asarray(len(ids), jnp.int32))
     got = np.stack([np.asarray(logits[0, -1], np.float32),
