@@ -294,15 +294,42 @@ def attn_fullseq(cfg, run, p, x, positions, **kw):
 # ---------------------------------------------------------------------------
 
 
-def _cache_update(cache, new, idx):
-    """Write ``new`` (B,1,...) at position idx of cache (B,M,...)."""
-    zeros = (0,) * (cache.ndim - 2)
-    return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype),
-                                        (0, idx) + zeros)
+def write_rows(cache, rows, idx):
+    """Write each leaf of ``rows`` (L,B,1,...) at position idx of the
+    stacked cache leaf of the same name (L,B,M,...); other leaves pass.
+    Where the cache's M is sharded, the partitioner writes inside each
+    shard and moves no part of the cache (``tests/dist_checks.py``)."""
+    out = dict(cache)
+    for name, row in rows.items():
+        c = cache[name]
+        out[name] = jax.lax.dynamic_update_slice(
+            c, row.astype(c.dtype), (0, 0, idx) + (0,) * (c.ndim - 3))
+    return out
+
+
+def _attend_past_and_own(pv, past, own, vals, own_vals, cache_len, scale):
+    """One softmax over the scores of the cached positions before
+    cache_len (past, (...,T)) and of the step's own row (own, (...,1)),
+    then the probabilities times the values: einsum ``pv`` over the cache
+    ``vals`` plus the same over ``own_vals``, summed in f32 and rounded
+    once to the cache's dtype."""
+    f32 = jnp.float32
+    T = past.shape[-1]
+    past = jnp.where(jnp.arange(T) < cache_len, past.astype(f32) * scale,
+                     -1e30)
+    probs = jax.nn.softmax(jnp.concatenate([past, own.astype(f32) * scale],
+                                           -1), -1).astype(vals.dtype)
+    out = (jnp.einsum(pv, probs[..., :T], vals, preferred_element_type=f32)
+           + jnp.einsum(pv, probs[..., T:], own_vals,
+                        preferred_element_type=f32))
+    return out.astype(vals.dtype)
 
 
 def gqa_decode(cfg, run, p, x, kc, vc, cache_len, *, mrope_positions=None):
-    """x (B,1,d); kc/vc (B,M,Hkv,Dh). Returns (out, new_kc, new_vc)."""
+    """x (B,1,d); kc/vc (B,M,Hkv,Dh), read only. Attends over the cached
+    positions before cache_len and this step's own key and value, in one
+    softmax. Returns (out, k, v): k/v (B,1,Hkv,Dh) the step's row, in the
+    cache's dtype, for the caller to write at cache_len."""
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
@@ -313,57 +340,61 @@ def gqa_decode(cfg, run, p, x, kc, vc, cache_len, *, mrope_positions=None):
     if mrope_positions is None and cfg.rope_kind == "mrope":
         mrope_positions = jnp.broadcast_to(positions[None], (3, B, 1))
     q, k = _rope_q_k(cfg, p, q, k, positions, mrope_positions)
+    # rounded to the cache's dtype first: the same numbers as a write
+    # followed by a read
+    k, v = k.astype(kc.dtype), v.astype(vc.dtype)
 
     if run.decode_attn == "seq_sharded" and run.mesh is not None:
         from repro.distributed.decode_attn import gqa_decode_seq_sharded
-        out, kc, vc = gqa_decode_seq_sharded(
+        out = gqa_decode_seq_sharded(
             q, k, v, kc, vc, cache_len, mesh=run.mesh,
             seq_axis=run.seq_axis, batch_axes=run.batch_axes)
     else:
-        kc = _cache_update(kc, k, cache_len)
-        vc = _cache_update(vc, v, cache_len)
-        G = Hq // Hkv
-        qg = q.reshape(B, Hkv, G, hd)
-        logits = jnp.einsum("bkgd,btkd->bkgt", qg, kc).astype(jnp.float32)
-        T = kc.shape[1]
-        mask = (jnp.arange(T) <= cache_len)[None, None, None, :]
-        logits = jnp.where(mask, logits * hd ** -0.5, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bkgt,btkv->bkgv", probs.astype(vc.dtype), vc)
-        out = out.reshape(B, 1, Hq * hd)
-    return Lyr.dense(p["wo"], out.reshape(B, 1, Hq * hd)), kc, vc
+        qg = q.reshape(B, Hkv, Hq // Hkv, hd)
+        score = "bkgd,btkd->bkgt"
+        out = _attend_past_and_own(
+            "bkgt,btkv->bkgv", jnp.einsum(score, qg, kc),
+            jnp.einsum(score, qg, k), vc, v, cache_len, hd ** -0.5)
+    return Lyr.dense(p["wo"], out.reshape(B, 1, Hq * hd)), k, v
 
 
 def mla_decode(cfg, run, p, x, ckv_c, krope_c, cache_len):
-    """Absorbed decode over the compressed cache (B,M,r)/(B,M,dr)."""
+    """Absorbed decode over the compressed cache (B,M,r)/(B,M,dr), read
+    only, and this step's own latent row, in one softmax. Returns
+    (out, ckv (B,1,r), krope (B,1,dr)): the step's row, in the cache's
+    dtype, for the caller to write at cache_len."""
     B = x.shape[0]
     H, vd = cfg.n_heads, cfg.v_head_dim
     positions = jnp.full((B, 1), cache_len, jnp.int32)
     qn, qr = Mla._project_q(cfg, p, x)
     qr = Mla.rope(cfg, qr, positions)
     ckv_new, krope_new = Mla._project_ckv(cfg, p, x, positions)
+    ckv_new = ckv_new.astype(ckv_c.dtype)
+    krope_new = krope_new[:, :, 0, :].astype(krope_c.dtype)
     q_c = Lyr.weight_einsum("bshn,rhn->bshr", qn, p["wuk"])
     scale = Mla.softmax_scale(cfg)
 
     if run.decode_attn == "seq_sharded" and run.mesh is not None:
         from repro.distributed.decode_attn import mla_decode_seq_sharded
-        out_c, ckv_c, krope_c = mla_decode_seq_sharded(
-            q_c, qr, ckv_new, krope_new[:, :, 0, :], ckv_c, krope_c,
+        out_c = mla_decode_seq_sharded(
+            q_c, qr, ckv_new, krope_new, ckv_c, krope_c,
             cache_len, scale, mesh=run.mesh, seq_axis=run.seq_axis,
             batch_axes=run.batch_axes)
     else:
-        ckv_c = _cache_update(ckv_c, ckv_new, cache_len)
-        krope_c = _cache_update(krope_c, krope_new[:, :, 0, :], cache_len)
-        T = ckv_c.shape[1]
-        logits = (jnp.einsum("bshr,btr->bhst", q_c, ckv_c)
-                  + jnp.einsum("bshr,btr->bhst", qr, krope_c))
-        logits = logits.astype(jnp.float32)
-        mask = (jnp.arange(T) <= cache_len)[None, None, None, :]
-        logits = jnp.where(mask, logits * scale, -1e30)
-        probs = jax.nn.softmax(logits, -1).astype(ckv_c.dtype)
-        out_c = jnp.einsum("bhst,btr->bshr", probs, ckv_c)
+        score = "bshr,btr->bhst"
+
+        def scores(ckv, krope):
+            return (jnp.einsum(score, q_c, ckv)
+                    + jnp.einsum(score, qr, krope))
+
+        # the value dot in its own output order (b,h,s,r): XLA's CPU
+        # backend has no bf16 dot with an f32 result in another order
+        out_c = jnp.moveaxis(_attend_past_and_own(
+            "bhst,btr->bhsr", scores(ckv_c, krope_c),
+            scores(ckv_new, krope_new), ckv_c, ckv_new, cache_len, scale),
+            1, 2)
     out = Lyr.weight_einsum("bshr,rhv->bshv", out_c, p["wuv"])
-    return Lyr.dense(p["wo"], out.reshape(B, 1, H * vd)), ckv_c, krope_c
+    return Lyr.dense(p["wo"], out.reshape(B, 1, H * vd)), ckv_new, krope_new
 
 
 # ---------------------------------------------------------------------------
@@ -443,9 +474,11 @@ def block_fullseq(cfg, run, p, x, positions, *, kind, mrope_positions=None,
 
 def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
                  mrope_positions=None):
-    """One layer, one token. cache_sl = this layer's cache slice (no L dim).
-    Returns (x, new_cache, routes), ``routes`` as ``apply_moe`` gives
-    them (None but for an expert layer)."""
+    """One layer, one token. cache_sl = this layer's cache slice (no L dim),
+    read only. Returns (x, new, routes): ``new`` the step's cache rows
+    (B,1,...) of an attention layer, to be written at cache_len, or an
+    SSM layer's whole new state; ``routes`` as ``apply_moe`` gives them
+    (None but for an expert layer)."""
     routes = None
     if kind in ("dense", "moe", "moe_dense0", "dec"):
         xin = Lyr.rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -453,12 +486,12 @@ def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
             h, ckv, krope = mla_decode(cfg, run, p["attn"], xin,
                                        cache_sl["ckv"], cache_sl["krope"],
                                        cache_len)
-            new_cache = {"ckv": ckv, "krope": krope}
+            new = {"ckv": ckv, "krope": krope}
         else:
-            h, kc, vc = gqa_decode(cfg, run, p["attn"], xin, cache_sl["k"],
-                                   cache_sl["v"], cache_len,
-                                   mrope_positions=mrope_positions)
-            new_cache = {"k": kc, "v": vc}
+            h, k, v = gqa_decode(cfg, run, p["attn"], xin, cache_sl["k"],
+                                 cache_sl["v"], cache_len,
+                                 mrope_positions=mrope_positions)
+            new = {"k": k, "v": v}
         x = x + h
         if kind == "dec":
             B = x.shape[0]
@@ -469,13 +502,12 @@ def block_decode(cfg, run, p, x, cache_sl, cache_len, *, kind,
                                              None, hd ** -0.5)
             x = x + Lyr.dense(p["xattn"]["wo"],
                               out.reshape(B, 1, cfg.n_heads * hd))
-            new_cache["xk"], new_cache["xv"] = cache_sl["xk"], cache_sl["xv"]
         xn = Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps)
         if kind == "moe":
             h2, _, routes = apply_moe(cfg, run, p["moe"], xn)
         else:
             h2 = Lyr.mlp(cfg, p["mlp"], xn)
-        return x + h2, new_cache, routes
+        return x + h2, new, routes
     if kind == "ssm":
         h, (hs, conv) = Ssm.ssm_decode(cfg, p["ssm"],
                                        Lyr.rmsnorm(p["ln"], x, cfg.norm_eps),
@@ -801,7 +833,9 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
 
     Returns (logits (B,1,V), new_cache, routes): ``routes`` (L_moe, B, K),
     the experts each token routed to in each scanned expert layer, where
-    the expert layer reports them (``apply_moe``), else None.
+    the expert layer reports them (``apply_moe``), else None. Attention
+    layers read the cache as given and return their rows; one write per
+    leaf then places all layers' rows at cache_len (``write_rows``).
     """
     routes = None
     x = embed_tokens(cfg, params, token, run.stream_dtype)
@@ -811,15 +845,16 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
         x = x + jax.lax.dynamic_slice_in_dim(
             Lyr.sinusoidal_positions(cache.get("k").shape[2], cfg.d_model),
             cache_len, 1, axis=0)[None].astype(x.dtype)
-        new_layers = []
+        rows = []
         for i in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
             csl = jax.tree_util.tree_map(lambda a: a[i], cache)
-            x, nc, _ = block_decode(cfg, run, lp, x, csl, cache_len,
-                                    kind="dec")
-            new_layers.append(nc)
-        new_cache = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
-                                           *new_layers)
+            x, r, _ = block_decode(cfg, run, lp, x, csl, cache_len,
+                                   kind="dec")
+            rows.append(r)
+        new_cache = write_rows(
+            cache, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *rows),
+            cache_len)
     elif cfg.family == "hybrid":
         k_every = cfg.hybrid_attn_every
         hs, convs, aks, avs = [], [], [], []
@@ -828,14 +863,14 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
             if k_every and i % k_every == 0:
                 sp = params["shared"]
                 xin = Lyr.rmsnorm(sp["ln1"], x, cfg.norm_eps)
-                h, kc, vc = gqa_decode(cfg, run, sp["attn"], xin,
-                                       cache["ak"][inv], cache["av"][inv],
-                                       cache_len)
+                h, k, v = gqa_decode(cfg, run, sp["attn"], xin,
+                                     cache["ak"][inv], cache["av"][inv],
+                                     cache_len)
                 x = x + h
                 x = x + Lyr.mlp(cfg, sp["mlp"],
                                 Lyr.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-                aks.append(kc)
-                avs.append(vc)
+                aks.append(k)
+                avs.append(v)
                 inv += 1
             lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
             csl = {"h": cache["h"][i], "conv": cache["conv"][i]}
@@ -843,52 +878,56 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
                                     kind="ssm")
             hs.append(nc["h"])
             convs.append(nc["conv"])
-        new_cache = {"h": jnp.stack(hs), "conv": jnp.stack(convs),
-                     "ak": jnp.stack(aks), "av": jnp.stack(avs)}
+        new_cache = write_rows(
+            {**cache, "h": jnp.stack(hs), "conv": jnp.stack(convs)},
+            {"ak": jnp.stack(aks), "av": jnp.stack(avs)}, cache_len)
     else:
-        # uniform stack: scan over (blocks, cache layers). MoE stacks with a
-        # leading dense layer run dense0 as a python loop, then scan the
-        # uniform remainder.
+        # uniform stack: scan the layers over their index into the cache,
+        # so each reads its slice in place (a scan over the cache itself,
+        # or over a slice of it, copies it). MoE stacks with a leading
+        # dense layer run dense0 as a python loop, then scan the remainder.
         n_dense0 = cfg.first_dense_layers if cfg.family == "moe" else 0
-        new_cache_parts = []
-        if n_dense0:
-            c0 = jax.tree_util.tree_map(lambda a: a[:n_dense0], cache)
-            for i in range(n_dense0):
-                lp = jax.tree_util.tree_map(lambda a: a[i], params["dense0"])
-                csl = jax.tree_util.tree_map(lambda a: a[i], c0)
-                x, nc, _ = block_decode(cfg, run, lp, x, csl, cache_len,
-                                        kind="moe_dense0",
-                                        mrope_positions=mrope_positions)
-                new_cache_parts.append(
-                    jax.tree_util.tree_map(lambda a: a[None], nc))
-            cache_main = jax.tree_util.tree_map(lambda a: a[n_dense0:], cache)
-        else:
-            cache_main = cache
+
+        def layer_of(i):
+            return jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False),
+                cache)
+
+        parts = []
+        for i in range(n_dense0):
+            lp = jax.tree_util.tree_map(lambda a: a[i], params["dense0"])
+            x, r, _ = block_decode(cfg, run, lp, x, layer_of(i), cache_len,
+                                   kind="moe_dense0",
+                                   mrope_positions=mrope_positions)
+            parts.append(jax.tree_util.tree_map(lambda a: a[None], r))
 
         def scan_body(x, inp):
-            lp, csl = inp
-            x, nc, r = block_decode(cfg, run, lp, x, csl, cache_len,
+            lp, i = inp
+            x, r, rt = block_decode(cfg, run, lp, x, layer_of(i), cache_len,
                                     kind=kind,
                                     mrope_positions=mrope_positions)
-            return x, (nc, r)
+            return x, (r, rt)
 
+        n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
         if run.scan_layers:
-            x, (nc_main, routes) = jax.lax.scan(
-                scan_body, x, (params["blocks"], cache_main))
+            x, (rows, routes) = jax.lax.scan(
+                scan_body, x,
+                (params["blocks"], n_dense0 + jnp.arange(n, dtype=jnp.int32)))
         else:
             outs = []
-            n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
             for i in range(n):
                 lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                csl = jax.tree_util.tree_map(lambda a: a[i], cache_main)
-                x, out = scan_body(x, (lp, csl))
+                x, out = scan_body(x, (lp, n_dense0 + i))
                 outs.append(out)
-            nc_main, routes = jax.tree_util.tree_map(
+            rows, routes = jax.tree_util.tree_map(
                 lambda *a: jnp.stack(a), *outs)
-        new_cache_parts.append(nc_main)
-        new_cache = jax.tree_util.tree_map(
-            lambda *a: jnp.concatenate(a, axis=0), *new_cache_parts) \
-            if len(new_cache_parts) > 1 else new_cache_parts[0]
+        parts.append(rows)
+        new = jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a, axis=0), *parts) \
+            if len(parts) > 1 else parts[0]
+        # an SSM layer returns its whole new state, not a row
+        new_cache = new if cfg.family == "ssm" else \
+            write_rows(cache, new, cache_len)
 
     return lm_logits(cfg, run, params, x), new_cache, routes
 

@@ -84,13 +84,18 @@ class Engine:
     def _decode_chunk_impl(self, params, token, cache, cache_len, rng,
                            temperature, live):
         """Runs ``chunk`` decode steps. live: (B,) bool — dead slots decode
-        but their cache writes are masked out (slot freed semantics).
-        Returns (token, cache, cache_len, tokens (B, chunk)), and where the
-        expert layers report their routes (the dropless serving layer) a
-        fifth output, int32 (3,), summed over expert layers and the steps
-        with a live slot: the held experts that at least one live slot
-        routed to, the layer steps, and the held experts those layer steps
-        read (all of them: the dropless layer reads every held expert)."""
+        and keep their token. Each step writes every slot's row at
+        cache_len, dead slots' too: a dead slot's row is read by no live
+        slot (attention never reads across slots, and the dropless expert
+        layer lets no token reach another's answer), and admission
+        rewrites a slot's whole ``max_len`` row (``_write_slot``) before
+        the slot is read again. Returns (token, cache, cache_len, tokens
+        (B, chunk)), and where the expert layers report their routes (the
+        dropless serving layer) a fifth output, int32 (3,), summed over
+        expert layers and the steps with a live slot: the held experts
+        that at least one live slot routed to, the layer steps, and the
+        held experts those layer steps read (all of them: the dropless
+        layer reads every held expert)."""
 
         def body(carry, _):
             tok, cache, clen, rng = carry
@@ -101,10 +106,6 @@ class Engine:
             nxt = nxt.astype(jnp.int32)
             keep = live[:, None]
             nxt = jnp.where(keep, nxt, tok)
-            new_cache = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(
-                    jnp.reshape(live, (1, -1) + (1,) * (n.ndim - 2)), n, o),
-                new_cache, cache)
             touched = None if routes is None else \
                 _experts_touched(self.cfg, routes, live)
             return (nxt, new_cache, clen + 1, rng), (nxt[:, 0], touched)
