@@ -1,5 +1,5 @@
-"""Benchmark driver: one module per paper table/figure + the roofline
-aggregation. CSV on stdout; JSON artifacts in experiments/bench/.
+"""Benchmark driver: one module per paper table/figure. CSV on stdout;
+JSON artifacts in experiments/bench/.
 
   PYTHONPATH=src python -m benchmarks.run            # all
   PYTHONPATH=src python -m benchmarks.run fig3 table1
@@ -11,7 +11,7 @@ import sys
 import time
 
 from benchmarks import (bench_batched_serve, fig3_latency, fig4_scaling,
-                        gen_cost, table1_hitrate, table2_threshold, roofline)
+                        gen_cost, table1_hitrate, table2_threshold)
 
 BENCHES = {
     "fig3": fig3_latency.main,
@@ -19,7 +19,6 @@ BENCHES = {
     "table2": table2_threshold.main,
     "fig4": fig4_scaling.main,
     "gen_cost": gen_cost.main,
-    "roofline": roofline.main,
     "batched_serve": lambda: bench_batched_serve.main([]),
 }
 

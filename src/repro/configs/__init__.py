@@ -1,5 +1,5 @@
-from repro.configs.base import (ModelConfig, ShapeConfig, SHAPES, get_config,
-                                list_configs, reduced, register)
+from repro.configs.base import (ModelConfig, get_config, list_configs,
+                                reduced, register)
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "get_config",
-           "list_configs", "reduced", "register"]
+__all__ = ["ModelConfig", "get_config", "list_configs", "reduced",
+           "register"]

@@ -1,4 +1,4 @@
-"""Model/shape/mesh configuration system.
+"""Model configuration system.
 
 Every assigned architecture is a ``ModelConfig`` in its own module under
 ``repro.configs``; ``get_config(name)`` resolves it. ``reduced()`` produces the
@@ -7,28 +7,7 @@ CPU-smoke-test variant of any config (same family / same code paths, tiny dims).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
-
-# ---------------------------------------------------------------------------
-# Input shapes assigned to the LM pool (seq_len x global_batch).
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
+from typing import Tuple
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -96,9 +75,6 @@ class ModelConfig:
     frontend: str = "none"  # none | audio | vision
     # --- numerics -------------------------------------------------------------
     dtype: str = "bfloat16"
-    # --- remat / scan ----------------------------------------------------------
-    remat: bool = True
-    scan_layers: bool = True
 
     # ------------------------------------------------------------------
     @property

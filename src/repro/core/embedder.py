@@ -116,7 +116,7 @@ class MiniLMEncoder:
                                          self.cfg.dim)[None]
         pos = jnp.broadcast_to(jnp.arange(tokens.shape[1], dtype=jnp.int32),
                                tokens.shape)
-        run = M.RunCfg(attn_impl="naive", remat=False, scan_layers=True)
+        run = M.RunCfg(attn_impl="naive", remat=False)
         x, _, _ = M._scan_stack(self.mcfg, run, params["blocks"], x, pos,
                                 kind="enc", build_cache=False)
         x = Lyr.rmsnorm(params["final_norm"], x, 1e-6)

@@ -78,8 +78,7 @@ def spec_for(shape, logical_axes, mesh) -> P:
 # llama3.2-3b whose 24 heads don't divide 16). Non-divisible q-heads =>
 # replicate the projection (redundant compute over "model", zero resharding
 # — what Megatron does when TP > heads); divisible q-heads with
-# non-divisible kv-heads => Megatron-GQA kv replication (+ repeat_kv in the
-# attention kernel).
+# non-divisible kv-heads => Megatron-GQA kv replication.
 # ---------------------------------------------------------------------------
 
 PARAM_RULES = [

@@ -1,17 +1,10 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-``make_production_mesh`` is a FUNCTION (never a module-level constant) so
-importing this module does not touch jax device state. The dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import to obtain 512 placeholder CPU devices.
-
-Mesh topology (TPU v5e target):
-  single pod : (data=16, model=16)              = 256 chips
-  multi-pod  : (pod=2, data=16, model=16)       = 512 chips
+Functions only (never a module-level constant), so importing this module
+does not touch jax device state.
 
 Axis roles:
-  pod   — outermost data parallelism (pure gradient all-reduce; crosses DCI)
-  data  — FSDP / batch sharding within a pod
+  data  — batch sharding
   model — tensor parallel (heads/ffn/vocab), expert parallel (MoE),
           KV-sequence parallel (flash-decoding), index-row parallel (MIPS)
 """
@@ -21,14 +14,8 @@ import jax
 import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / small dry-runs)."""
+    """Arbitrary mesh (tests / small multi-device checks)."""
     return jax.make_mesh(tuple(shape), tuple(axes))
 
 
@@ -38,15 +25,3 @@ def make_local_mesh(model: int = 1, data: int = 1):
     assert model * data <= n, (model, data, n)
     devs = np.asarray(jax.devices()[: model * data]).reshape(data, model)
     return jax.sharding.Mesh(devs, ("data", "model"))
-
-
-def batch_axes_of(mesh) -> tuple:
-    """Axes a global batch dim shards over (everything except 'model')."""
-    return tuple(a for a in mesh.axis_names if a != "model")
-
-
-def n_batch_shards(mesh) -> int:
-    n = 1
-    for a in batch_axes_of(mesh):
-        n *= mesh.shape[a]
-    return n

@@ -10,9 +10,9 @@ Conventions
   axis (init via ``jax.vmap``) and applied with ``lax.scan`` (+ optional
   remat) — constant compile size at any depth. Hybrid (38L, non-uniform) and
   whisper (6+6L) apply their stacked params with a Python loop.
-* ``RunCfg`` carries implementation choices (attention schedule, MoE
-  dispatch, decode sharding) so the same model code serves smoke tests,
-  the 512-device dry-run, and the §Perf hillclimb variants.
+* ``RunCfg`` carries implementation choices (attention impl, MoE dispatch,
+  decode sharding, stream dtype); the serving engine runs one fixed
+  ``RunCfg`` (``serving.engine.SERVE_RUN``).
 * Full-seq attention defaults to the blockwise flash path (never
   materializes S x T); ``naive`` is the small-shape oracle.
 
@@ -46,41 +46,22 @@ from repro.models.blockwise import blockwise_gqa
 @dataclasses.dataclass(frozen=True)
 class RunCfg:
     attn_impl: str = "blockwise"      # naive | blockwise
-    schedule: str = "rect"            # rect | tri  (causal block skipping)
     q_block: int = 512
     kv_block: int = 1024
-    moe_impl: str = "scatter"         # scatter | einsum | ep | dropless
-    moe_group: int = 2048
+    moe_impl: str = "scatter"         # scatter | ep | dropless
     remat: bool = True
-    scan_layers: bool = True
     decode_attn: str = "naive"        # naive | seq_sharded
     mesh: Any = None                  # jax Mesh for shard_map paths
     ep_axis: str = "model"
     seq_axis: str = "model"
     batch_axes: Tuple[str, ...] = ("data",)
-    aux_coef: float = 0.01
     stream_dtype: Any = None          # residual stream dtype (None: the
                                       # params'); serving keeps f32 over
                                       # bf16 params, which every matmul
                                       # then takes in bf16 (Lyr.dense)
-    heads_sharded: bool = False       # q-heads TP-shard over "model"
-    repeat_kv: bool = False           # Megatron-GQA: kv replicated+repeated
-    ssm_chunk: int = 0                # override cfg.ssm_chunk (0 = cfg's);
-                                      # SSD chunking is exact at any size —
-                                      # this is a memory/compute tile knob
-    seq_parallel: bool = False        # Megatron-SP: residual stream sharded
-                                      # over ("model", seq) between layers —
-                                      # GSPMD derives RS+AG instead of AR
-    pin_ssm: bool = False             # pin SSD internals to batch-only
-                                      # sharding (stops GSPMD speculative
-                                      # seq-sharding -> halo permutes)
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)
-
-
-SMOKE = RunCfg(attn_impl="naive", remat=False, q_block=64, kv_block=64,
-               moe_group=64)
 
 
 # ---------------------------------------------------------------------------
@@ -208,18 +189,13 @@ def _rope_q_k(cfg, p, q, k, positions, mrope_positions):
 
 def _batch_cb(run):
     """Sharding-constraint callback for blockwise attention tiles: pins the
-    batch dim to the batch axes and (when q-heads are TP-sharded) the head
-    dim to "model" — with_sharding_constraint treats unlisted dims as
-    replicated, so the head dim must be named explicitly or the constraint
-    itself would gather head-sharded tiles."""
+    batch dim to the batch axes."""
     if run.mesh is None:
         return None
 
-    def cb(t, bdim, hdim=None):
+    def cb(t, bdim):
         spec = [None] * t.ndim
         spec[bdim] = run.batch_axes
-        if run.heads_sharded and hdim is not None:
-            spec[hdim] = "model"
         return _constrain(t, run, *spec)
 
     return cb
@@ -227,33 +203,26 @@ def _batch_cb(run):
 
 def gqa_fullseq(cfg, run, p, x, positions, *, mrope_positions=None,
                 mask_offset=0, causal=True):
-    """Returns (out (B,S,d), kv dict) for train/prefill."""
+    """Returns (out (B,S,d), kv dict) for forward/prefill."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     q = Lyr.dense(p["wq"], x).reshape(B, S, cfg.n_heads, hd)
     k = Lyr.dense(p["wk"], x).reshape(B, S, cfg.n_kv_heads, hd)
     v = Lyr.dense(p["wv"], x).reshape(B, S, cfg.n_kv_heads, hd)
     q, k = _rope_q_k(cfg, p, q, k, positions, mrope_positions)
-    G = cfg.n_heads // cfg.n_kv_heads
-    ka, va = k, v
-    if run.repeat_kv and G > 1:
-        # Megatron-GQA: kv heads replicated over "model"; repeat to full
-        # head count so the attention einsums shard cleanly on q-heads.
-        ka = jnp.repeat(k, G, axis=2)
-        va = jnp.repeat(v, G, axis=2)
     if run.attn_impl == "naive":
         mask = Lyr.causal_mask(S, S, mask_offset) if causal else None
-        out = Lyr.gqa_scores_softmax_out(q, ka, va, mask, hd ** -0.5)
+        out = Lyr.gqa_scores_softmax_out(q, k, v, mask, hd ** -0.5)
     else:
-        out = blockwise_gqa(q, ka, va, causal=causal, mask_offset=mask_offset,
+        out = blockwise_gqa(q, k, v, causal=causal, mask_offset=mask_offset,
                             q_block=run.q_block, kv_block=run.kv_block,
-                            schedule=run.schedule, constrain=_batch_cb(run))
+                            constrain=_batch_cb(run))
     return Lyr.dense(p["wo"], out.reshape(B, S, cfg.n_heads * hd)), \
         {"k": k, "v": v}
 
 
 def mla_fullseq(cfg, run, p, x, positions, *, mask_offset=0):
-    """MLA train/prefill: expand compressed KV per head, blockwise attention.
+    """MLA forward/prefill: expand compressed KV per head, blockwise attention.
 
     Returns (out, {"ckv","krope"}) — the cache stays compressed.
     """
@@ -275,7 +244,7 @@ def mla_fullseq(cfg, run, p, x, positions, *, mask_offset=0):
     else:
         out = blockwise_gqa(q, k, v, causal=True, mask_offset=mask_offset,
                             q_block=run.q_block, kv_block=run.kv_block,
-                            schedule=run.schedule, constrain=_batch_cb(run),
+                            constrain=_batch_cb(run),
                             scale=Mla.softmax_scale(cfg))
     return Lyr.dense(p["wo"], out.reshape(B, S, H * vd)), \
         {"ckv": ckv, "krope": krope[:, :, 0, :]}
@@ -409,9 +378,7 @@ def apply_moe(cfg, run, p, x):
     if run.moe_impl == "dropless":
         y, routes = Moe.moe_ffn_dropless(cfg, p, x)
         return y, jnp.zeros((), jnp.float32), routes
-    if run.moe_impl == "einsum":
-        y, aux = Moe.moe_ffn_einsum(cfg, p, x, run.moe_group)
-    elif run.moe_impl == "ep":
+    if run.moe_impl == "ep":
         from repro.distributed.moe_parallel import moe_ffn_ep
         y, aux = moe_ffn_ep(cfg, p, x, mesh=run.mesh, ep_axis=run.ep_axis,
                             batch_axes=run.batch_axes)
@@ -463,11 +430,8 @@ def block_fullseq(cfg, run, p, x, positions, *, kind, mrope_positions=None,
             h2 = Lyr.mlp(cfg, p["mlp"], Lyr.rmsnorm(p["ln2"], x, cfg.norm_eps))
         return x + h2, aux, kv
     if kind == "ssm":
-        cb = _batch_cb(run) if run.pin_ssm else None
         h, state = Ssm.ssm_forward(cfg, p["ssm"],
-                                   Lyr.rmsnorm(p["ln"], x, cfg.norm_eps),
-                                   chunk=run.ssm_chunk or None,
-                                   constrain=cb)
+                                   Lyr.rmsnorm(p["ln"], x, cfg.norm_eps))
         return x + h, aux, {"h": state[0], "conv": state[1]}
     raise ValueError(kind)
 
@@ -562,7 +526,7 @@ def lm_logits(cfg, run, params, x):
 
 
 # ---------------------------------------------------------------------------
-# Forward (train) / prefill
+# Forward / prefill
 # ---------------------------------------------------------------------------
 
 
@@ -615,8 +579,7 @@ def _scan_stack(cfg, run, blocks, x, positions, *, kind, build_cache,
         # remat-saved residual stack (observed: layer inputs stored in BOTH
         # bf16 and f32, ~2x activation memory on deep stacks)
         x = _grad_safe_barrier(x)
-        seq_ax = "model" if run.seq_parallel else None
-        x = _constrain(x, run, run.batch_axes, seq_ax, None)
+        x = _constrain(x, run, run.batch_axes, None, None)
         x, aux, kv = block_fullseq(cfg, run, lp, x, positions, kind=kind,
                                    mrope_positions=mrope_positions,
                                    mask_offset=mask_offset)
@@ -624,21 +587,8 @@ def _scan_stack(cfg, run, blocks, x, positions, *, kind, build_cache,
 
     if run.remat:
         body = jax.checkpoint(body)
-    if run.scan_layers:
-        x, (auxs, kvs) = jax.lax.scan(body, x, blocks)
-        return x, jnp.sum(auxs), (kvs if build_cache else None)
-    n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    auxs, kvs = [], []
-    for i in range(n):
-        lp = jax.tree_util.tree_map(lambda a: a[i], blocks)
-        x, (aux, kv) = body(x, lp)
-        auxs.append(aux)
-        kvs.append(kv)
-    aux = jnp.sum(jnp.stack(auxs))
-    if build_cache:
-        kvs = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *kvs)
-        return x, aux, kvs
-    return x, aux, None
+    x, (auxs, kvs) = jax.lax.scan(body, x, blocks)
+    return x, jnp.sum(auxs), (kvs if build_cache else None)
 
 
 def _hybrid_fullseq(cfg, run, params, x, positions, build_cache):
@@ -776,7 +726,7 @@ def prefill(cfg, params, batch, run=RunCfg(), max_len=None):
 
 
 # ---------------------------------------------------------------------------
-# Decode cache allocation (for dry-run / serving without a prefill pass)
+# Decode cache allocation (serving without a prefill pass)
 # ---------------------------------------------------------------------------
 
 
@@ -909,18 +859,9 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
             return x, (r, rt)
 
         n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
-        if run.scan_layers:
-            x, (rows, routes) = jax.lax.scan(
-                scan_body, x,
-                (params["blocks"], n_dense0 + jnp.arange(n, dtype=jnp.int32)))
-        else:
-            outs = []
-            for i in range(n):
-                lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                x, out = scan_body(x, (lp, n_dense0 + i))
-                outs.append(out)
-            rows, routes = jax.tree_util.tree_map(
-                lambda *a: jnp.stack(a), *outs)
+        x, (rows, routes) = jax.lax.scan(
+            scan_body, x,
+            (params["blocks"], n_dense0 + jnp.arange(n, dtype=jnp.int32)))
         parts.append(rows)
         new = jax.tree_util.tree_map(
             lambda *a: jnp.concatenate(a, axis=0), *parts) \
@@ -930,45 +871,3 @@ def decode_step(cfg, params, token, cache, cache_len, run=RunCfg(),
             write_rows(cache, new, cache_len)
 
     return lm_logits(cfg, run, params, x), new_cache, routes
-
-
-def serve_step(cfg, params, token, cache, cache_len, rng, run=RunCfg(),
-               temperature=0.0):
-    """decode_step + sampling -> (next_token (B,1), new_cache)."""
-    logits, new_cache, _ = decode_step(cfg, params, token, cache, cache_len,
-                                       run)
-    lg = logits[:, -1, :].astype(jnp.float32)
-    if temperature and temperature > 0:
-        nxt = jax.random.categorical(rng, lg / temperature, axis=-1)
-    else:
-        nxt = jnp.argmax(lg, axis=-1)
-    return nxt[:, None].astype(jnp.int32), new_cache
-
-
-# ---------------------------------------------------------------------------
-# Loss
-# ---------------------------------------------------------------------------
-
-
-def lm_loss(cfg, params, batch, run=RunCfg()):
-    """Causal LM cross-entropy (labels == -1 ignored) + MoE aux.
-
-    Written shard-wise over the vocab dim: the lse reduction and the
-    one-hot pick both reduce over V, so with logits constrained to
-    (batch, None, "model") GSPMD lowers them to local reductions + psum
-    instead of gathering the (B,S,V) tensor.
-    """
-    logits, aux = forward(cfg, params, batch, run)
-    labels = batch["labels"]
-    lg = logits.astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(lg, axis=-1, keepdims=True))
-    lse = jnp.log(jnp.sum(jnp.exp(lg - m), axis=-1)) + m[..., 0]
-    onehot = jax.nn.one_hot(jnp.maximum(labels, 0), cfg.padded_vocab,
-                            dtype=lg.dtype)
-    onehot = _constrain(onehot, run, run.batch_axes, None, "model")
-    picked = jnp.sum(lg * onehot, axis=-1)
-    nll = lse - picked
-    valid = (labels >= 0).astype(jnp.float32)
-    loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1.0)
-    return loss + run.aux_coef * aux["moe_aux"], {
-        "loss": loss, "moe_aux": aux["moe_aux"]}

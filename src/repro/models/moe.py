@@ -162,59 +162,3 @@ def moe_ffn_dropless(cfg, p, x):
     if cfg.n_shared_experts:
         y = y + mlp(cfg, p["shared"], x2d)
     return y.reshape(B, S, d).astype(x.dtype), idx
-
-
-# ---------------------------------------------------------------------------
-# GShard-style grouped einsum dispatch (GSPMD-friendly: all matmuls).
-# ---------------------------------------------------------------------------
-
-
-def combine_tensor(cfg, idx, w, valid, C):
-    """(g,K) expert ids + weights -> (g, E, C) combine weights (f32)."""
-    E = cfg.n_experts
-    # rank of each (token, k) within its expert, computed per group
-    flat_e = idx.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    rank = jnp.cumsum(onehot, axis=0) - onehot
-    rank = jnp.take_along_axis(rank, flat_e[:, None], axis=1)[:, 0]
-    rank = rank.reshape(idx.shape)                                # (g, K)
-    ok = valid & (rank < C)
-    oh_e = jax.nn.one_hot(idx, E, dtype=jnp.float32)              # (g,K,E)
-    oh_c = jax.nn.one_hot(rank, C, dtype=jnp.float32)             # (g,K,C)
-    comb = jnp.einsum("gk,gke,gkc->gec",
-                      w * ok.astype(jnp.float32), oh_e, oh_c)
-    return comb
-
-
-def moe_ffn_einsum(cfg, p, x, group_size=2048):
-    """GShard-style dispatch: (groups, g, E, C) combine tensors + einsums.
-
-    Shards cleanly under GSPMD (groups follow the token/batch sharding, the
-    expert dim or d_ff can be TP-sharded). Preferred when experts are fat
-    (grok: d_ff 32768) so dispatch FLOPs amortize; thin-expert models
-    (deepseek) use the shard_map EP path in repro.distributed.moe_parallel.
-    """
-    _whole(cfg)
-    B, S, d = x.shape
-    T = B * S
-    g = min(group_size, T)
-    n_groups = T // g
-    assert n_groups * g == T, (T, g)
-    x2d = x.reshape(T, d)
-    w, idx, probs = route(cfg, p, x2d)
-    C = capacity(cfg, g)
-
-    def one_group(xg, wg, ig):
-        comb = combine_tensor(cfg, ig, wg, jnp.ones(ig.shape, bool), C)
-        disp = (comb > 0).astype(xg.dtype)                        # (g,E,C)
-        buf = jnp.einsum("gec,gd->ecd", disp, xg)                 # (E,C,d)
-        out = expert_ffn(cfg, p["experts"], buf)                  # (E,C,d)
-        return jnp.einsum("gec,ecd->gd", comb.astype(xg.dtype), out)
-
-    y = jax.vmap(one_group)(x2d.reshape(n_groups, g, d),
-                            w.reshape(n_groups, g, cfg.experts_per_tok),
-                            idx.reshape(n_groups, g, cfg.experts_per_tok))
-    y = y.reshape(T, d)
-    if cfg.n_shared_experts:
-        y = y + mlp(cfg, p["shared"], x2d)
-    return y.reshape(B, S, d), load_balance_loss(cfg, probs, idx)

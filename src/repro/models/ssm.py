@@ -130,24 +130,15 @@ def ssd_chunked(xdt, dA, Bm, Cm, chunk, h0=None):
     return y.astype(xdt.dtype), h_final
 
 
-def ssm_forward(cfg, p, x, state=None, conv_state=None, chunk=None,
-                constrain=None):
-    """Full-sequence (train/prefill) Mamba2 block. Returns (y, (h, conv_state)).
-
-    ``constrain(t, batch_dim)``: optional sharding pin applied to the wide
-    intermediates — without it GSPMD speculatively seq-shards the SSD scan
-    and pays halo collective-permutes every chunk (measured 1.1 GB/layer on
-    mamba2 prefill_32k).
-    """
+def ssm_forward(cfg, p, x, state=None, conv_state=None):
+    """Full-sequence (forward/prefill) Mamba2 block. Returns
+    (y, (h, conv_state))."""
     B, S, _ = x.shape
-    chunk = chunk or cfg.ssm_chunk
-    cb = constrain or (lambda t, b: t)
     H, P = cfg.n_ssm_heads, cfg.ssm_head_dim
     G, N = cfg.ssm_n_groups, cfg.ssm_state
     di = cfg.d_inner_ssm
     z, xBC_raw, dt = _split_in_proj(cfg, dense(p["in_proj"], x))
-    xBC_raw = cb(xBC_raw, 0)
-    xBC = cb(causal_conv(p, xBC_raw), 0)
+    xBC = causal_conv(p, xBC_raw)
     xs = xBC[..., :di].reshape(B, S, H, P)
     Bm = xBC[..., di:di + G * N].reshape(B, S, G, N)
     Cm = xBC[..., di + G * N:].reshape(B, S, G, N)
@@ -155,9 +146,7 @@ def ssm_forward(cfg, p, x, state=None, conv_state=None, chunk=None,
     A = -jnp.exp(p["A_log"])                                              # (H,)
     dA = dt * A                                                           # (B,S,H)
     xdt = xs * dt[..., None].astype(xs.dtype)
-    xdt = cb(xdt, 0)
-    y, h = ssd_chunked(xdt, dA, Bm, Cm, min(chunk, S), h0=state)
-    y = cb(y, 0)
+    y, h = ssd_chunked(xdt, dA, Bm, Cm, min(cfg.ssm_chunk, S), h0=state)
     y = y + xs * p["D"][None, None, :, None].astype(xs.dtype)
     y = y.reshape(B, S, di)
     y = gated_rmsnorm(p["norm"], y, z, cfg.norm_eps)

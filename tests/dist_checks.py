@@ -1,8 +1,7 @@
 """Multi-device distributed checks, run under 8 forced host devices.
 
 Executed by tests/test_distributed.py via subprocess (the main pytest
-process must keep seeing 1 device — the dry-run is the only other place the
-device count is forced). Asserts:
+process must keep seeing 1 device). Asserts:
 
   1. sharded MIPS top-k == flat reference on a (data=2, model=4) mesh
   2. seq-sharded GQA decode == naive decode attention
@@ -11,7 +10,6 @@ device count is forced). Asserts:
      part of the cache between devices
   4. EP (all-to-all) MoE == local scatter MoE, forward AND gradients
   5. param sharding rules produce valid NamedShardings for all 10 archs
-  6. elastic re-shard: checkpoint saved from one mesh restores onto another
 """
 import os
 
@@ -207,23 +205,6 @@ def check_param_specs_all_archs(mesh):
     print("ok param_specs_all_archs")
 
 
-def check_elastic_reshard(tmp, mesh_a, mesh_b):
-    from repro.training import checkpoint as CK
-    cfg = reduced(get_config("qwen3-1.7b"))
-    params = M.init_model(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    sh_a = Sh.param_shardings(params, mesh_a, cfg)
-    params_a = jax.tree_util.tree_map(jax.device_put, params, sh_a)
-    ck = CK.Checkpointer(tmp)
-    ck.save(1, {"params": params_a}, blocking=True)
-    # restore onto a DIFFERENT mesh shape
-    sh_b = Sh.param_shardings(params, mesh_b, cfg)
-    state, _ = ck.restore(shardings={"params": sh_b})
-    for a, b in zip(jax.tree_util.tree_leaves(params),
-                    jax.tree_util.tree_leaves(state["params"])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    print("ok elastic_reshard")
-
-
 if __name__ == "__main__":
     assert len(jax.devices()) == 8, jax.devices()
     mesh = make_mesh((2, 4), ("data", "model"))
@@ -233,8 +214,4 @@ if __name__ == "__main__":
     check_seq_sharded_decode_step()
     check_ep_moe_matches_scatter(mesh)
     check_param_specs_all_archs(mesh)
-    import tempfile
-    with tempfile.TemporaryDirectory() as td:
-        mesh_b = make_mesh((4, 2), ("data", "model"))
-        check_elastic_reshard(td, mesh, mesh_b)
     print("ALL DISTRIBUTED CHECKS PASSED")
